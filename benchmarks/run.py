@@ -14,6 +14,8 @@ import argparse
 import json
 import time
 
+from repro import compile_cache
+
 ALL = ("carbon", "scalability", "arrival", "renewables", "costs", "scenarios",
        "engine", "roofline", "micro")
 
@@ -51,6 +53,7 @@ def main() -> None:
     ap.add_argument("--json", default=None, metavar="PATH",
                     help="also write the rows as JSON to PATH")
     args = ap.parse_args()
+    compile_cache.enable()
     which = tuple(args.only.split(",")) if args.only else ALL
 
     rows = ["name,us_per_call,derived"]

@@ -12,6 +12,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.core.game import GameContext, cloud_objective, uniform_fractions
 from repro.core.schedulers import run_day
 from repro.dcsim import env as E
@@ -33,4 +34,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

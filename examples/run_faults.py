@@ -28,7 +28,7 @@ import time
 
 import numpy as np
 
-from repro import faults
+from repro import compile_cache, faults
 from repro.core import ExperimentSpec, run, sweep
 from repro.dcsim import env as E
 
@@ -105,4 +105,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

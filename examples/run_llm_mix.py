@@ -25,7 +25,7 @@ import time
 
 import numpy as np
 
-from repro import scenarios as S
+from repro import compile_cache, scenarios as S
 from repro.core import ExperimentSpec, run
 from repro.core import gt_drl
 from repro.core.ddpg import DDPGConfig
@@ -113,4 +113,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

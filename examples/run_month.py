@@ -14,7 +14,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import argparse
 import time
 
-from repro import scenarios as S
+from repro import compile_cache, scenarios as S
 from repro.core.schedulers import TECHNIQUES, run_month
 from repro.dcsim import env as E
 
@@ -50,4 +50,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

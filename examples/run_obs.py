@@ -22,7 +22,7 @@ import time
 
 import numpy as np
 
-from repro import obs
+from repro import compile_cache, obs
 from repro.core import ExperimentSpec, run
 from repro.core.ddpg import DDPGConfig
 from repro.core.force_directed import FDConfig
@@ -120,4 +120,5 @@ def obs_engine_key(spec):
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -22,7 +22,7 @@ import time
 import jax
 import jax.numpy as jnp
 
-from repro import scenarios as S
+from repro import compile_cache, scenarios as S
 from repro.core import schedulers as SCH
 from repro.core.game import GameContext
 from repro.dcsim import env as E
@@ -106,4 +106,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

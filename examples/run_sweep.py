@@ -23,7 +23,7 @@ import time
 
 import jax.numpy as jnp
 
-from repro import scenarios as S
+from repro import compile_cache, scenarios as S
 from repro.core import ExperimentSpec, register_technique, sweep
 from repro.core.force_directed import FDConfig, solve_epoch as fd_solve
 from repro.core.game import GameContext, SolveResult
@@ -108,4 +108,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

@@ -10,6 +10,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 import argparse
 
+from repro import compile_cache
 from repro.core.schedulers import TECHNIQUES, run_day
 from repro.dcsim import env as E
 
@@ -42,4 +43,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

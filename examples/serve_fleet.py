@@ -15,6 +15,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.core import gt_drl
 from repro.core.game import GameContext, fractions_to_ar
 from repro.dcsim import env as E
@@ -47,4 +48,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

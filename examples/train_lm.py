@@ -15,6 +15,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 import argparse
 import dataclasses
 
+from repro import compile_cache
 from repro.configs import get_config
 from repro.launch.train import train_loop
 
@@ -66,4 +67,5 @@ def main():
 
 
 if __name__ == "__main__":
+    compile_cache.enable()
     main()

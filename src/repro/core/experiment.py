@@ -265,7 +265,6 @@ def _sharded_batch(core: Callable, faulted: bool = False,
     EXACT unsharded program and N devices evaluate N env shards in parallel
     with zero cross-device collectives.
     """
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, PartitionSpec as P
 
     mesh = Mesh(np.asarray(jax.devices()), ("env",))
@@ -274,10 +273,10 @@ def _sharded_batch(core: Callable, faulted: bool = False,
     specs = (P("env"), P("env"), P(), P()) + (
         ((P("env") if fault_axis else P()),) if faulted else ())
     batched = jax.vmap(core, in_axes=axes)
-    fn = shard_map(batched, mesh=mesh,
-                   in_specs=specs,
-                   out_specs=(P("env"), P("env"), P("env")),
-                   check_rep=False)
+    fn = jax.shard_map(batched, mesh=mesh,
+                       in_specs=specs,
+                       out_specs=(P("env"), P("env"), P("env")),
+                       check_vma=False)
     return jax.jit(fn)
 
 

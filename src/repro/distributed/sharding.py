@@ -77,8 +77,6 @@ def _maybe(mesh: Mesh, axes, dim: int):
         return None
     if dim % axis_size(mesh, axes) != 0:
         return None
-    if isinstance(axes, (tuple, list)) and len(axes) == 1:
-        return axes[0]  # older jax PartitionSpec doesn't equate ('x',) == 'x'
     return axes
 
 

@@ -82,7 +82,9 @@ def _redistribute(kept: jnp.ndarray, over: jnp.ndarray, cap: jnp.ndarray,
         head = jnp.maximum(cap - kept, 0.0)                       # (I, D)
         w = head[:, None, :] * kern[None, :, :]                   # (I, Df, Dt)
         w = w / jnp.maximum(jnp.sum(w, axis=-1, keepdims=True), _EPS)
-        inc = jnp.einsum("if,ift->it", over, w)                   # (I, D)
+        # full f32: at DEFAULT precision a TPU contracts in one bf16 pass
+        inc = jnp.einsum("if,ift->it", over, w,
+                         precision=jax.lax.Precision.HIGHEST)     # (I, D)
         acc = jnp.minimum(inc, head)
         return (kept + acc, inc - acc), None
 

@@ -9,7 +9,8 @@ TPU-native design:
     bound; KV bytes dominate);
   * the kv dimension is sequential ("arbitrary") and carries the online
     softmax state in VMEM scratch, exactly like the prefill kernel;
-  * ragged cache lengths are masked from a lane-replicated lengths operand.
+  * ragged cache lengths arrive as a scalar-prefetch operand in SMEM, so
+    each program reads its own sequence's length as a scalar.
 
 For multi-megabyte caches a real deployment would add a second split-KV grid
 axis plus a cross-block reduction; block-sequential streaming is already
@@ -26,18 +27,15 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-# renamed TPUCompilerParams -> CompilerParams across jax versions
-_CompilerParams = getattr(pltpu, "CompilerParams", None) or pltpu.TPUCompilerParams
-
 NEG_INF = -0.7 * float(jnp.finfo(jnp.float32).max)
 LANES = 128
 
 
 def _decode_kernel(
+    len_ref,     # (B,) int32 valid lengths, scalar-prefetched into SMEM
     q_ref,       # (1, 1, group, d)
     k_ref,       # (1, 1, block_k, d)
     v_ref,       # (1, 1, block_k, d)
-    len_ref,     # (1, LANES) int32, lane-replicated valid length
     o_ref,       # (1, 1, group, d)
     m_scr, l_scr, acc_scr,
     *,
@@ -45,6 +43,7 @@ def _decode_kernel(
     softcap: float,
     block_k: int,
 ):
+    bi = pl.program_id(0)
     ki = pl.program_id(2)
     nk = pl.num_programs(2)
 
@@ -54,7 +53,7 @@ def _decode_kernel(
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    length = len_ref[0, 0]
+    length = len_ref[bi]
     k_pos = ki * block_k + jax.lax.broadcasted_iota(jnp.int32, (1, block_k), 1)
 
     @pl.when(ki * block_k < length)
@@ -118,30 +117,33 @@ def decode_attention(
     qt = q.reshape(b, kvh, group, d)
     kt = k_cache.transpose(0, 2, 1, 3)  # (B, KVH, S, D)
     vt = v_cache.transpose(0, 2, 1, 3)
-    len_rep = jnp.broadcast_to(lengths.astype(jnp.int32)[:, None], (b, LANES))
 
     kernel = functools.partial(
         _decode_kernel, sm_scale=scale, softcap=softcap, block_k=block_k
     )
-    out = pl.pallas_call(
-        kernel,
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
         grid=(b, kvh, nk),
         in_specs=[
-            pl.BlockSpec((1, 1, group, d), lambda b_, h_, ki: (b_, h_, 0, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, ki: (b_, h_, ki, 0)),
-            pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, ki: (b_, h_, ki, 0)),
-            pl.BlockSpec((1, LANES), lambda b_, h_, ki: (b_, 0)),
+            pl.BlockSpec((1, 1, group, d), lambda b_, h_, ki, lens: (b_, h_, 0, 0)),
+            pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, ki, lens: (b_, h_, ki, 0)),
+            pl.BlockSpec((1, 1, block_k, d), lambda b_, h_, ki, lens: (b_, h_, ki, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, group, d), lambda b_, h_, ki: (b_, h_, 0, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, kvh, group, d), q.dtype),
+        out_specs=pl.BlockSpec((1, 1, group, d),
+                               lambda b_, h_, ki, lens: (b_, h_, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((group, LANES), jnp.float32),
             pltpu.VMEM((group, LANES), jnp.float32),
             pltpu.VMEM((group, d), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, kvh, group, d), q.dtype),
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")
         ),
         interpret=interpret,
-    )(qt, kt, vt, len_rep)
+    )(lengths.astype(jnp.int32), qt, kt, vt)
     return out.reshape(b, h, d)

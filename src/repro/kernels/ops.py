@@ -21,10 +21,7 @@ from .flash_attention import flash_attention as _flash_pallas
 
 
 def _on_tpu() -> bool:
-    try:
-        return jax.default_backend() == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+    return jax.default_backend() == "tpu"
 
 
 def attention(

@@ -17,12 +17,8 @@ from jax.sharding import Mesh
 
 
 def _mesh(shape, axes) -> Mesh:
-    # jax.sharding.AxisType landed after 0.4.x; older jax defaults every
-    # axis to Auto already, so only pass axis_types when it exists.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is not None:
-        return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

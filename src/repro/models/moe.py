@@ -189,7 +189,6 @@ def moe_apply_ep(
 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """Expert-parallel MoE under the active mesh; falls back to the global
     formulation when un-meshed or the batch does not divide the DP axes."""
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
 
     mesh = shd.active_mesh()
@@ -252,12 +251,12 @@ def moe_apply_ep(
         return y.reshape(bl, s_, d_), aux
 
     dp_spec = dp_axes if dp_axes else None
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(P(dp_spec, None, None), P(), P("model", None, None),
                   P("model", None, None), P("model", None, None)),
         out_specs=(P(dp_spec, None, None), P()),
-        check_rep=False,
+        check_vma=False,
     )(x, rw, experts["w_in"]["w"], experts["w_gate"]["w"], experts["w_out"]["w"])
 
     y = y.astype(x.dtype)

@@ -16,9 +16,10 @@ tell them apart. Three pieces:
   evictions (``note_eviction``, fired by ``register_technique(overwrite=
   True)`` / ``unregister_technique``). ``cache_stats()`` is the queryable
   view; a test asserts the taps-off path adds zero compiles.
-- ``profile(label)`` — optional ``jax.profiler`` trace dropped under
+- ``profile(label)`` — a ``jax.profiler`` trace dropped under
   ``runs/profiles/<label>`` for kernel-level work (the ROADMAP's Pallas
-  item); degrades to a no-op warning where the profiler is unavailable.
+  item); raises where the profiler cannot start, so a run that asked for a
+  trace never finishes without one.
 
 Dispatch wrappers block on their outputs (``jax.block_until_ready``) so the
 recorded span covers the actual computation and every live tap callback has
@@ -29,7 +30,6 @@ from __future__ import annotations
 import collections
 import dataclasses
 import time
-import warnings
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional
 
@@ -216,20 +216,14 @@ def reset_stats() -> None:
 def profile(label: str = "trace", logdir: str = "runs/profiles"):
     """Drop a ``jax.profiler`` trace for the block under
     ``<logdir>/<label>`` (viewable in TensorBoard/Perfetto; the tool for
-    the queued Pallas-kernel work). Yields the trace directory, or ``None``
-    with a warning where the profiler is unavailable."""
+    the queued Pallas-kernel work). Yields the trace directory; an error
+    starting the profiler propagates."""
     import os
 
     import jax
     path = os.path.join(logdir, label)
-    try:
-        os.makedirs(path, exist_ok=True)
-        jax.profiler.start_trace(path)
-    except Exception as e:  # pragma: no cover - environment-dependent
-        warnings.warn(f"jax profiler unavailable ({e!r}); profile({label!r}) "
-                      "is a no-op")
-        yield None
-        return
+    os.makedirs(path, exist_ok=True)
+    jax.profiler.start_trace(path)
     try:
         yield path
     finally:

@@ -19,7 +19,6 @@ from typing import Any, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 from .adamw import _dequantize, _quantize
@@ -62,7 +61,7 @@ def compressed_psum(x: jnp.ndarray, mesh: Mesh, axis: str = "pod") -> jnp.ndarra
         return x
 
     @functools.partial(
-        shard_map, mesh=mesh,
+        jax.shard_map, mesh=mesh,
         in_specs=P(*([None] * x.ndim)),
         out_specs=P(*([None] * x.ndim)),
     )

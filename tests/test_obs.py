@@ -5,6 +5,7 @@ hits/misses/evictions, run records round-trip with full provenance, and the
 scoreboard renders from records alone."""
 import os
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -264,5 +265,17 @@ def test_bench_json_meta_carries_provenance():
 def test_profile_writes_a_trace_or_degrades_gracefully(tmp_path):
     with obs.profile("unit", logdir=str(tmp_path)) as p:
         jnp.dot(jnp.ones((8, 8)), jnp.ones((8, 8))).block_until_ready()
-    if p is not None:  # profiler available: the trace directory exists
-        assert os.path.isdir(p)
+    assert os.path.isdir(p)
+    traces = [f for _, _, fs in os.walk(p) for f in fs
+              if f.endswith(".xplane.pb")]
+    assert traces, f"no profiler trace written under {p}"
+
+
+def test_profile_raises_when_the_profiler_cannot_start(tmp_path, monkeypatch):
+    def refuse(path):
+        raise RuntimeError("profiler busy")
+
+    monkeypatch.setattr(jax.profiler, "start_trace", refuse)
+    with pytest.raises(RuntimeError, match="profiler busy"):
+        with obs.profile("unit", logdir=str(tmp_path)):
+            pass

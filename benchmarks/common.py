@@ -29,13 +29,8 @@ class Timer(obs.Span):
     def __init__(self):
         super().__init__(name="bench")
 
-    @property
-    def t0(self):  # legacy alias used by older bench scripts
-        return self._t0
-
 
 def emit(rows: List[str], name: str, seconds: float, derived: str):
     """CSV row: name, microseconds per call, derived metric string."""
-    obs.note_bench(name, seconds, derived)
     rows.append(f"{name},{seconds * 1e6:.0f},{derived}")
     print(rows[-1], flush=True)

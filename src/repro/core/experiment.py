@@ -214,26 +214,35 @@ def _day_core(technique: str, objective: str, hours: int, cfg,
     step = _solver_step(technique, cfg)
     guard_on = guard or faulted
 
+    # named scopes reach the compiled HLO's op_name metadata only, so a
+    # device trace's ops can be put down to a layer of the hour
     def _body(env, trace, carry, tau):
         key, peak, state = carry
         key, ks = jax.random.split(key)
         ctx = GameContext(env=env, tau=tau, objective=objective,
                           routed=routed)
-        state, res = step(ks, state, ctx, peak)
-        game.tap_nash_residual(ctx, res.fractions, peak)
+        with jax.named_scope("solver"):
+            state, res = step(ks, state, ctx, peak)
+        with jax.named_scope("nash_probe"):
+            game.tap_nash_residual(ctx, res.fractions, peak)
         fr = res.fractions
         if guard_on:
-            fr, fell_back = FL.guard_fractions(env, tau, fr)
-        ar = fractions_to_ar(ctx, fr)
+            with jax.named_scope("guard"):
+                fr, fell_back = FL.guard_fractions(env, tau, fr)
+        with jax.named_scope("project"):
+            ar = fractions_to_ar(ctx, fr)
         if faulted:
-            peak, m = FL.execute_hour(env, trace, peak, ar, tau, failover)
+            with jax.named_scope("failover"):
+                peak, m = FL.execute_hour(env, trace, peak, ar, tau, failover)
         else:
-            peak, m = E.step_epoch(env, peak, ar, tau)
+            with jax.named_scope("simulate"):
+                peak, m = E.step_epoch(env, peak, ar, tau)
         if guard_on:
             m = {**m, "fallback_hours": fell_back}
         tap_keys = _TAP_HOUR_KEYS + tuple(k for k in _FAULT_KEYS if k in m)
-        obs.tap("engine/hour",
-                {"tau": tau, **{k: m[k] for k in tap_keys}})
+        with jax.named_scope("tap"):
+            obs.tap("engine/hour",
+                    {"tau": tau, **{k: m[k] for k in tap_keys}})
         return (key, peak, state), m
 
     taus = functools.partial(jnp.arange, dtype=jnp.int32)
@@ -428,16 +437,37 @@ def _totals_keys(present) -> Tuple[str, ...]:
     return _TOTAL_KEYS + tuple(k for k in _FAULT_KEYS if k in present)
 
 
+def _fetch(ms: Mapping[str, Any], rows: Optional[int] = None
+           ) -> Dict[str, np.ndarray]:
+    """Copy each engine output to the host (its first ``rows`` rows)."""
+    with obs.span("engine.fetch"):
+        out = {k: np.asarray(v) for k, v in ms.items()}
+        if rows is not None:
+            out = {k: v[:rows] for k, v in out.items()}
+        obs.count("fetches", len(out))
+        return out
+
+
+def _dispatched(rows: int, fleet_hours: int) -> None:
+    """Count one engine call's env rows (padding included) and its real
+    fleet-hours on the current request."""
+    obs.count("rows", rows)
+    obs.count("fleet_hours", fleet_hours)
+
+
 def _format_day(ms, hours: int, technique: str, objective: str) -> Dict[str, Any]:
     """Stacked (hours,) metric arrays -> the run_day result dict."""
-    host = {k: np.asarray(v).astype(float).tolist() for k, v in ms.items()}
-    per_epoch = [{**{k: host[k][t] for k in host}, "tau": t} for t in range(hours)]
-    totals = {k: 0.0 for k in _totals_keys(host)}
-    for row in per_epoch:
-        for k in totals:
-            totals[k] += row[k]
-    return {"per_epoch": per_epoch, "totals": totals, "technique": technique,
-            "objective": objective}
+    host = _fetch(ms)
+    with obs.span("engine.format"):
+        host = {k: v.astype(float).tolist() for k, v in host.items()}
+        per_epoch = [{**{k: host[k][t] for k in host}, "tau": t}
+                     for t in range(hours)]
+        totals = {k: 0.0 for k in _totals_keys(host)}
+        for row in per_epoch:
+            for k in totals:
+                totals[k] += row[k]
+        return {"per_epoch": per_epoch, "totals": totals,
+                "technique": technique, "objective": objective}
 
 
 # ---------------------------------------------------------------------------
@@ -486,46 +516,48 @@ def run(
     — totals, convergence curves, engine timing spans, git/jax provenance —
     under ``runs/`` (see ``repro.obs.records``).
     """
-    if shard and spec.engine != "batched":
-        raise ValueError("shard=True needs engine='batched', "
-                         f"got {spec.engine!r}")
-    if shard and spec.effective_taps():
-        raise ValueError("taps stream through jax.debug.callback, which the "
-                         "shard_map engine does not support; run shard=False "
-                         "when tapping")
-    if solver is not None and spec.engine != "loop":
-        raise ValueError("a prebuilt solver closure needs engine='loop', "
-                         f"got {spec.engine!r}")
-    if peak_state0 is not None and spec.engine == "batched":
-        raise ValueError("the batched engine starts every scenario-day from "
-                         "a zero peak; peak_state0 is not supported")
-    if solver_state0 is not None and spec.engine == "loop":
-        raise ValueError("the loop engine derives solver state from the "
-                         "seed or a prebuilt solver=; solver_state0 is "
-                         "scan/batched/month-only")
-    if faults is not None and spec.engine == "month":
-        raise ValueError("the month engine does not take realized faults "
-                         "yet (a FaultTrace describes one day); run faulted "
-                         "days through scan/loop/batched")
-    if _trace_stacked(faults) and spec.engine != "batched":
-        raise ValueError("a stacked (per-point) FaultTrace needs "
-                         f"engine='batched', got {spec.engine!r}; the "
-                         "scan/loop engines evaluate one env against one "
-                         "trace")
-    game.get_technique(spec.technique)  # fail fast with the known-names list
-    if spec.engine == "scan":
-        result = _run_scan(spec, envs, peak_state0, solver_state0, faults)
-    elif spec.engine == "loop":
-        result = _run_loop(spec, envs, peak_state0, solver, faults)
-    elif spec.engine == "batched":
-        result = _run_batched(spec, envs, solver_state0, shard, faults)
-    else:
-        result = _run_month(spec, envs, peak_state0, solver_state0)
-    if record:
-        _record_run(spec, result, shard=shard, path=record,
-                    faulted=faults is not None,
-                    fault_axis=_trace_stacked(faults))
-    return result
+    with obs.request("api.run"):
+        if shard and spec.engine != "batched":
+            raise ValueError("shard=True needs engine='batched', "
+                             f"got {spec.engine!r}")
+        if shard and spec.effective_taps():
+            raise ValueError("taps stream through jax.debug.callback, which the "
+                             "shard_map engine does not support; run shard=False "
+                             "when tapping")
+        if solver is not None and spec.engine != "loop":
+            raise ValueError("a prebuilt solver closure needs engine='loop', "
+                             f"got {spec.engine!r}")
+        if peak_state0 is not None and spec.engine == "batched":
+            raise ValueError("the batched engine starts every scenario-day from "
+                             "a zero peak; peak_state0 is not supported")
+        if solver_state0 is not None and spec.engine == "loop":
+            raise ValueError("the loop engine derives solver state from the "
+                             "seed or a prebuilt solver=; solver_state0 is "
+                             "scan/batched/month-only")
+        if faults is not None and spec.engine == "month":
+            raise ValueError("the month engine does not take realized faults "
+                             "yet (a FaultTrace describes one day); run faulted "
+                             "days through scan/loop/batched")
+        if _trace_stacked(faults) and spec.engine != "batched":
+            raise ValueError("a stacked (per-point) FaultTrace needs "
+                             f"engine='batched', got {spec.engine!r}; the "
+                             "scan/loop engines evaluate one env against one "
+                             "trace")
+        game.get_technique(spec.technique)  # fail fast with the known-names list
+        if spec.engine == "scan":
+            result = _run_scan(spec, envs, peak_state0, solver_state0, faults)
+        elif spec.engine == "loop":
+            result = _run_loop(spec, envs, peak_state0, solver, faults)
+        elif spec.engine == "batched":
+            result = _run_batched(spec, envs, solver_state0, shard, faults)
+        else:
+            result = _run_month(spec, envs, peak_state0, solver_state0)
+        if record:
+            with obs.span("run.record"):
+                _record_run(spec, result, shard=shard, path=record,
+                            faulted=faults is not None,
+                            fault_axis=_trace_stacked(faults))
+        return result
 
 
 def _record_run(spec: ExperimentSpec, result: Dict[str, Any], *,
@@ -542,12 +574,14 @@ def _record_run(spec: ExperimentSpec, result: Dict[str, Any], *,
 
 
 def _run_scan(spec, env, peak_state0, solver_state0, faults=None):
-    key, state0 = _day_inputs(env, spec.technique, spec.objective, spec.seed,
-                              spec.pretrain, spec.cfg, solver_state0,
-                              spec.routed)
-    peak0 = (peak_state0 if peak_state0 is not None
-             else jnp.zeros((E.num_dcs(env),)))
+    with obs.span("engine.inputs"):
+        key, state0 = _day_inputs(env, spec.technique, spec.objective,
+                                  spec.seed, spec.pretrain, spec.cfg,
+                                  solver_state0, spec.routed)
+        peak0 = (peak_state0 if peak_state0 is not None
+                 else jnp.zeros((E.num_dcs(env),)))
     day = _compiled(*_engine_key(spec, faulted=faults is not None))
+    _dispatched(1, spec.hours)
     if faults is None:
         _, _, ms = day(env, key, peak0, state0)
     else:
@@ -598,53 +632,57 @@ def _run_loop(spec, env, peak_state0, solver, faults=None):
         if guard_on:
             m = {**m, "fallback_hours": fell_back}
         epoch_metrics.append(m)  # stays on device; no per-epoch host sync
-    per_epoch: List[Dict[str, float]] = []
-    totals = {k: 0.0
-              for k in _totals_keys(epoch_metrics[0] if epoch_metrics else ())}
-    for tau, m in enumerate(jax.device_get(epoch_metrics)):  # ONE transfer
-        row = {k: float(v) for k, v in m.items()}
-        row["tau"] = tau
-        per_epoch.append(row)
-        for k in totals:
-            totals[k] += row[k]
-    return {"per_epoch": per_epoch, "totals": totals,
-            "technique": spec.technique, "objective": spec.objective}
+    _dispatched(1, spec.hours)
+    with obs.span("engine.fetch"):
+        host_metrics = jax.device_get(epoch_metrics)  # ONE transfer
+        obs.count("fetches", sum(len(m) for m in epoch_metrics))
+    with obs.span("engine.format"):
+        per_epoch: List[Dict[str, float]] = []
+        totals = {k: 0.0 for k in _totals_keys(
+            epoch_metrics[0] if epoch_metrics else ())}
+        for tau, m in enumerate(host_metrics):
+            row = {k: float(v) for k, v in m.items()}
+            row["tau"] = tau
+            per_epoch.append(row)
+            for k in totals:
+                totals[k] += row[k]
+        return {"per_epoch": per_epoch, "totals": totals,
+                "technique": spec.technique, "objective": spec.objective}
 
 
 def _run_batched(spec, envs, solver_state0, shard, faults=None):
-    if isinstance(envs, E.EnvParams) and envs.er.ndim == 2:
-        envs = [envs]  # single env == batch of one (compare_techniques parity)
-    if isinstance(envs, E.EnvParams):
-        env_b, n = envs, int(envs.er.shape[0])
-        env0 = jax.tree_util.tree_map(lambda x: x[0], envs)
-    else:
-        envs = list(envs)
-        env_b, n = E.stack_envs(envs), len(envs)
-        env0 = envs[0]
-    seeds = list(range(n)) if spec.seeds is None else list(spec.seeds)
-    if len(seeds) != n:
-        raise ValueError(f"{len(seeds)} seeds for {n} scenario-days")
+    with obs.span("engine.inputs"):
+        if isinstance(envs, E.EnvParams) and envs.er.ndim == 2:
+            envs = [envs]  # single env == batch of one (compare_techniques parity)
+        if isinstance(envs, E.EnvParams):
+            env_b, n = envs, int(envs.er.shape[0])
+            env0 = jax.tree_util.tree_map(lambda x: x[0], envs)
+        else:
+            envs = list(envs)
+            env_b, n = E.stack_envs(envs), len(envs)
+            env0 = envs[0]
+        seeds = list(range(n)) if spec.seeds is None else list(spec.seeds)
+        if len(seeds) != n:
+            raise ValueError(f"{len(seeds)} seeds for {n} scenario-days")
 
-    # per-day keys split exactly as run_day splits them; gt-drl pretrains
-    # ONCE on the first seed's pretrain key (deploy-once semantics)
-    keys = jnp.stack([jax.random.split(jax.random.PRNGKey(s))[1] for s in seeds])
-    _, state0 = _day_inputs(env0, spec.technique, spec.objective, seeds[0],
-                            spec.pretrain, spec.cfg, solver_state0, spec.routed)
-    peak0 = jnp.zeros((E.num_dcs(env0),))
+        # per-day keys split exactly as run_day splits them; gt-drl
+        # pretrains ONCE on the first seed's pretrain key (deploy-once
+        # semantics)
+        keys = jnp.stack([jax.random.split(jax.random.PRNGKey(s))[1]
+                          for s in seeds])
+        _, state0 = _day_inputs(env0, spec.technique, spec.objective,
+                                seeds[0], spec.pretrain, spec.cfg,
+                                solver_state0, spec.routed)
+        peak0 = jnp.zeros((E.num_dcs(env0),))
 
-    faulted = faults is not None
-    stacked = _trace_stacked(faults)  # per-row traces vs one shared trace
-    if stacked and int(faults.avail_mult.shape[0]) != n:
-        raise ValueError(
-            f"stacked FaultTrace has {int(faults.avail_mult.shape[0])} rows "
-            f"for {n} scenario-days")
-    trace = (faults,) if faulted else ()
-    if not shard:
-        batch = _compiled(*_engine_key(spec, faulted=faulted,
-                                       fault_axis=stacked))
-        _, _, ms = batch(env_b, keys, peak0, state0, *trace)
-    else:
-        pad = (-n) % jax.device_count()
+        faulted = faults is not None
+        stacked = _trace_stacked(faults)  # per-row traces vs one shared trace
+        if stacked and int(faults.avail_mult.shape[0]) != n:
+            raise ValueError(
+                f"stacked FaultTrace has {int(faults.avail_mult.shape[0])} "
+                f"rows for {n} scenario-days")
+        trace = (faults,) if faulted else ()
+        pad = (-n) % jax.device_count() if shard else 0
         if pad:
             env_b = E.pad_env_batch(env_b, n + pad)
             keys = jnp.concatenate(
@@ -654,49 +692,56 @@ def _run_batched(spec, envs, solver_state0, shard, faults=None):
                     lambda x: jnp.concatenate(
                         [x, jnp.broadcast_to(x[-1:], (pad,) + x.shape[1:])]),
                     faults),)
-        batch = _compiled(*_engine_key(spec, shard=True, faulted=faulted,
-                                       fault_axis=stacked))
-        _, _, ms = batch(env_b, keys, peak0, state0, *trace)
-        if pad:
-            ms = {k: v[:n] for k, v in ms.items()}
-    out = {k: np.asarray(v) for k, v in ms.items()}  # (n, hours) each
-    totals = {k: out[k].sum(axis=1) for k in _totals_keys(out)}
-    return {"totals": totals, "per_epoch": out, "technique": spec.technique,
-            "objective": spec.objective, "seeds": seeds}
+    batch = _compiled(*_engine_key(spec, shard=shard, faulted=faulted,
+                                   fault_axis=stacked))
+    _dispatched(n + pad, n * spec.hours)
+    _, _, ms = batch(env_b, keys, peak0, state0, *trace)
+    out = _fetch(ms, n if pad else None)  # (n, hours) each
+    with obs.span("engine.format"):
+        totals = {k: out[k].sum(axis=1) for k in _totals_keys(out)}
+        return {"totals": totals, "per_epoch": out,
+                "technique": spec.technique, "objective": spec.objective,
+                "seeds": seeds}
 
 
 def _run_month(spec, envs, peak_state0, solver_state0):
     days = spec.days
-    if isinstance(envs, E.EnvParams) and envs.er.ndim == 2:
-        n = 30 if days is None else int(days)
-        env0, env_days = envs, E.tile_env(envs, n)
-    elif isinstance(envs, E.EnvParams):
-        n = int(envs.er.shape[0])
-        env0 = jax.tree_util.tree_map(lambda x: x[0], envs)
-        env_days = envs
-    else:
-        envs = [e if isinstance(e, E.EnvParams) else e[1] for e in envs]
-        n, env0, env_days = len(envs), envs[0], E.stack_envs(envs)
-    if days is not None and int(days) != n:
-        raise ValueError(f"days={days} but {n} per-day envs were given")
+    with obs.span("engine.inputs"):
+        if isinstance(envs, E.EnvParams) and envs.er.ndim == 2:
+            n = 30 if days is None else int(days)
+            env0, env_days = envs, E.tile_env(envs, n)
+        elif isinstance(envs, E.EnvParams):
+            n = int(envs.er.shape[0])
+            env0 = jax.tree_util.tree_map(lambda x: x[0], envs)
+            env_days = envs
+        else:
+            envs = [e if isinstance(e, E.EnvParams) else e[1] for e in envs]
+            n, env0, env_days = len(envs), envs[0], E.stack_envs(envs)
+        if days is not None and int(days) != n:
+            raise ValueError(f"days={days} but {n} per-day envs were given")
 
-    keys = jnp.stack(
-        [jax.random.split(jax.random.PRNGKey(spec.seed + d))[1]
-         for d in range(n)])
-    _, state0 = _day_inputs(env0, spec.technique, spec.objective, spec.seed,
-                            spec.pretrain, spec.cfg, solver_state0, spec.routed)
-    peak0 = (peak_state0 if peak_state0 is not None
-             else jnp.zeros((E.num_dcs(env0),)))
+        keys = jnp.stack(
+            [jax.random.split(jax.random.PRNGKey(spec.seed + d))[1]
+             for d in range(n)])
+        _, state0 = _day_inputs(env0, spec.technique, spec.objective,
+                                spec.seed, spec.pretrain, spec.cfg,
+                                solver_state0, spec.routed)
+        peak0 = (peak_state0 if peak_state0 is not None
+                 else jnp.zeros((E.num_dcs(env0),)))
 
     month = _compiled(*_engine_key(spec))
+    _dispatched(n, n * spec.hours)
     final_peak, _, ms, peaks = month(env_days, keys, peak0, state0)
-    per_day = {k: np.asarray(v) for k, v in ms.items()}  # (n, hours) each
-    day_totals = {k: per_day[k].sum(axis=1) for k in _TOTAL_KEYS}
-    return {"per_day": per_day, "day_totals": day_totals,
-            "totals": {k: float(day_totals[k].sum()) for k in _TOTAL_KEYS},
-            "peak_w": np.asarray(peaks), "final_peak_w": np.asarray(final_peak),
-            "days": n, "technique": spec.technique,
-            "objective": spec.objective}
+    per_day = _fetch(ms)  # (n, hours) each
+    host = _fetch({"peaks": peaks, "final_peak": final_peak})
+    with obs.span("engine.format"):
+        day_totals = {k: per_day[k].sum(axis=1) for k in _TOTAL_KEYS}
+        return {"per_day": per_day, "day_totals": day_totals,
+                "totals": {k: float(day_totals[k].sum())
+                           for k in _TOTAL_KEYS},
+                "peak_w": host["peaks"], "final_peak_w": host["final_peak"],
+                "days": n, "technique": spec.technique,
+                "objective": spec.objective}
 
 
 # ---------------------------------------------------------------------------
@@ -757,66 +802,78 @@ def sweep(
     """
     from .. import scenarios as S
 
-    base_env = base_env if base_env is not None else E.build_env(4, seed=0)
-    points, rows = S.build_grid(base_env, grid, base=base_scenarios)
-    labels = [lbl for lbl, _ in rows]
-    envs = [env for _, env in rows]
-    n = len(rows)
-    techniques = tuple(techniques) if techniques else (spec.technique,)
-    overrides = dict(cfg_overrides or {})
+    with obs.request("api.sweep"):
+        with obs.span("sweep.grid"):
+            if base_env is None:
+                base_env = E.build_env(4, seed=0)
+            points, rows = S.build_grid(base_env, grid, base=base_scenarios)
+            labels = [lbl for lbl, _ in rows]
+            envs = [env for _, env in rows]
+        n = len(rows)
+        techniques = tuple(techniques) if techniques else (spec.technique,)
+        overrides = dict(cfg_overrides or {})
 
-    if faults is not None and not isinstance(faults, FL.FaultTrace):
-        faults = FL.stack_traces(faults)  # sequence: one trace per point
-    if _trace_stacked(faults) and int(faults.avail_mult.shape[0]) != n:
-        raise ValueError(
-            f"per-point faults: {int(faults.avail_mult.shape[0])} traces "
-            f"for {n} grid points")
+        if faults is not None and not isinstance(faults, FL.FaultTrace):
+            with obs.span("sweep.stack"):
+                faults = FL.stack_traces(faults)  # one trace per point
+        if _trace_stacked(faults) and int(faults.avail_mult.shape[0]) != n:
+            raise ValueError(
+                f"per-point faults: {int(faults.avail_mult.shape[0])} traces "
+                f"for {n} grid points")
 
-    def point_spec(t, n_pts):
-        cfg = overrides.get(t, spec.cfg if t == spec.technique else None)
-        return spec.replace(technique=t, cfg=cfg, engine="batched",
-                            seeds=(spec.seed,) * n_pts)
+        def point_spec(t, n_pts):
+            cfg = overrides.get(t, spec.cfg if t == spec.technique else None)
+            return spec.replace(technique=t, cfg=cfg, engine="batched",
+                                seeds=(spec.seed,) * n_pts)
 
-    if resume_dir is not None:
-        results, resume_meta = _sweep_resumable(
-            point_spec, envs, techniques, labels, faults=faults,
-            shard=shard, resume_dir=resume_dir,
-            chunk_points=chunk_points or 1, max_retries=max_retries,
-            backoff_s=backoff_s, point_timeout_s=point_timeout_s)
-    else:
-        resume_meta = None
-        env_b = E.stack_envs(envs)
-        results = {}
-        for t in techniques:
-            pspec = point_spec(t, n)
-            res = _run_batched(pspec, env_b, None, shard, faults)
-            results[t] = {"totals": res["totals"],
-                          "per_epoch": res["per_epoch"]}
-    if record:
-        for t in techniques:
-            # one record per technique: each grid point's daily totals form
-            # the "curve" along the sweep's label axis
-            pspec = point_spec(t, n)
-            rec = obs.make_record(
-                pspec, {**results[t], "technique": t,
-                        "objective": spec.objective},
-                kind="sweep",
-                curves={k: np.asarray(v, dtype=float).tolist()
-                        for k, v in results[t]["totals"].items()},
-                engine_spans=obs.engine_stat(
-                    _engine_key(pspec, shard=shard,
-                                faulted=faults is not None,
-                                fault_axis=_trace_stacked(faults))),
-                extra={"labels": labels,
-                       "grid": {name: list(pts) for name, pts in grid.items()}})
-            obs.write_record(rec, record if isinstance(record, str) else None)
-    out = {"grid": {name: list(pts) for name, pts in grid.items()},
-           "points": points, "labels": labels, "results": results,
-           "objective": spec.objective, "hours": spec.hours,
-           "routed": spec.routed, "techniques": list(techniques)}
-    if resume_meta is not None:
-        out["resume"] = resume_meta
-    return out
+        if resume_dir is not None:
+            results, resume_meta = _sweep_resumable(
+                point_spec, envs, techniques, labels, faults=faults,
+                shard=shard, resume_dir=resume_dir,
+                chunk_points=chunk_points or 1, max_retries=max_retries,
+                backoff_s=backoff_s, point_timeout_s=point_timeout_s)
+        else:
+            resume_meta = None
+            with obs.span("sweep.stack"):
+                env_b = E.stack_envs(envs)
+            results = {}
+            for t in techniques:
+                pspec = point_spec(t, n)
+                res = _run_batched(pspec, env_b, None, shard, faults)
+                results[t] = {"totals": res["totals"],
+                              "per_epoch": res["per_epoch"]}
+        if record:
+            with obs.span("run.record"):
+                _record_sweep(spec, grid, point_spec, techniques, results,
+                              labels, shard=shard, faults=faults, path=record)
+        out = {"grid": {name: list(pts) for name, pts in grid.items()},
+               "points": points, "labels": labels, "results": results,
+               "objective": spec.objective, "hours": spec.hours,
+               "routed": spec.routed, "techniques": list(techniques)}
+        if resume_meta is not None:
+            out["resume"] = resume_meta
+        return out
+
+
+def _record_sweep(spec, grid, point_spec, techniques, results, labels, *,
+                  shard, faults, path) -> None:
+    """One JSONL RunRecord per technique of a finished sweep: each grid
+    point's daily totals form the "curve" along the sweep's label axis."""
+    n = len(labels)
+    for t in techniques:
+        pspec = point_spec(t, n)
+        rec = obs.make_record(
+            pspec, {**results[t], "technique": t,
+                    "objective": spec.objective},
+            kind="sweep",
+            curves={k: np.asarray(v, dtype=float).tolist()
+                    for k, v in results[t]["totals"].items()},
+            engine_spans=obs.engine_stat(
+                _engine_key(pspec, shard=shard, faulted=faults is not None,
+                            fault_axis=_trace_stacked(faults))),
+            extra={"labels": labels,
+                   "grid": {name: list(pts) for name, pts in grid.items()}})
+        obs.write_record(rec, path if isinstance(path, str) else None)
 
 
 def _sweep_resumable(point_spec, envs, techniques, labels, *, faults, shard,
@@ -861,19 +918,22 @@ def _sweep_resumable(point_spec, envs, techniques, labels, *, faults, shard,
     def step_fn(step):
         FL.check_kill_switch()
         t, start, end = plan[step]
-        pspec = point_spec(t, end - start)
-        env_b = E.stack_envs(envs[start:end])
-        chunk_faults = (jax.tree_util.tree_map(lambda x: x[start:end], faults)
-                        if stacked else faults)
-        t0 = _time.perf_counter()
-        res = FL.call_with_timeout(
-            lambda: _run_batched(pspec, env_b, None, shard, chunk_faults),
-            point_timeout_s, label=f"chunk {step} ({t}[{start}:{end}])")
-        monitor.record(step, _time.perf_counter() - t0)
-        pending[step] = {"totals": {k: np.asarray(v)
-                                    for k, v in res["totals"].items()},
-                         "per_epoch": {k: np.asarray(v)
-                                       for k, v in res["per_epoch"].items()}}
+        with obs.span("sweep.chunk", step=step):
+            pspec = point_spec(t, end - start)
+            with obs.span("sweep.stack"):
+                env_b = E.stack_envs(envs[start:end])
+                chunk_faults = (jax.tree_util.tree_map(
+                    lambda x: x[start:end], faults) if stacked else faults)
+            t0 = _time.perf_counter()
+            res = FL.call_with_timeout(
+                lambda: _run_batched(pspec, env_b, None, shard, chunk_faults),
+                point_timeout_s, label=f"chunk {step} ({t}[{start}:{end}])")
+            monitor.record(step, _time.perf_counter() - t0)
+            pending[step] = {
+                "totals": {k: np.asarray(v)
+                           for k, v in res["totals"].items()},
+                "per_epoch": {k: np.asarray(v)
+                              for k, v in res["per_epoch"].items()}}
         computed_steps.append(step)
 
     def save_fn(step_after):

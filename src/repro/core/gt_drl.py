@@ -291,8 +291,11 @@ def solve_epoch(
         agents, joint, best_joint, best_val, diverged = carry
         prev_agents, prev_joint = agents, joint
         k1, k2 = jax.random.split(key_r)
-        agents, joint = half_update(agents, joint, k1, 0, ctx, peak_state, cfg)
-        agents, joint = half_update(agents, joint, k2, 1, ctx, peak_state, cfg)
+        with jax.named_scope("best_response"):
+            agents, joint = half_update(agents, joint, k1, 0, ctx,
+                                        peak_state, cfg)
+            agents, joint = half_update(agents, joint, k2, 1, ctx,
+                                        peak_state, cfg)
         val = jnp.sum(player_rewards(ctx, joint, peak_state))
         ok = jnp.all(jnp.isfinite(joint)) & jnp.isfinite(val)
         agents = jax.tree_util.tree_map(

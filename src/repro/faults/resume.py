@@ -26,6 +26,7 @@ retry net never catches it — exactly like a real process death.
 from __future__ import annotations
 
 import contextlib
+import contextvars
 import threading
 from typing import Any, Callable, Dict, Optional
 
@@ -74,7 +75,9 @@ def call_with_timeout(fn: Callable[[], Any], timeout_s: Optional[float],
                       label: str = "chunk") -> Any:
     """Run ``fn()`` with a wall-time bound. Raises ``PointTimeout`` when it
     does not return in time (the worker thread is daemonic and abandoned —
-    a hung XLA compile cannot be cancelled, only failed past)."""
+    a hung XLA compile cannot be cancelled, only failed past). The worker
+    runs in a copy of the caller's context, so its ``obs`` spans stay under
+    the caller's span and request."""
     if not timeout_s:
         return fn()
     box: Dict[str, Any] = {}
@@ -85,7 +88,8 @@ def call_with_timeout(fn: Callable[[], Any], timeout_s: Optional[float],
         except BaseException as e:  # noqa: BLE001 - re-raised on the caller
             box["err"] = e
 
-    th = threading.Thread(target=target, daemon=True)
+    th = threading.Thread(target=contextvars.copy_context().run,
+                          args=(target,), daemon=True)
     th.start()
     th.join(timeout_s)
     if th.is_alive():

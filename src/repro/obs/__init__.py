@@ -1,6 +1,7 @@
 """``repro.obs`` — telemetry for the compiled evaluation engines.
 
-Three pieces, one invariant: **zero cost when disabled**.
+Three pieces. Taps cost nothing when disabled; spans are always on and
+change no output.
 
 - **Taps** (``repro.obs.tap``): named emission points inside jitted scan
   bodies (``obs.tap(name, value)``). Disabled taps compile to nothing —
@@ -20,12 +21,19 @@ Three pieces, one invariant: **zero cost when disabled**.
                             improve call
   ========================  ===================================================
 
-- **Spans** (``repro.obs.spans``): compile-cache accounting for the
+- **Spans** (``repro.obs.spans``): always on, about 2 µs each. Every
+  public ``run``/``sweep`` call is one request (``api.run``,
+  ``api.sweep``) whose child spans cover its host path (``sweep.grid``,
+  ``scenario.<transform>``, ``sweep.stack``, ``engine.inputs``,
+  ``engine.dispatch``, ``engine.fetch``, ``engine.format``) and whose
+  counters say what it did (``rows``, ``fleet_hours``, ``dispatches``,
+  ``fetches``): ``obs.requests(last=n)``. Each span is also a
+  ``jax.profiler.TraceAnnotation``, so it shows on the host plane of an
+  ``obs.profile(label)`` trace. Compile-cache accounting for the
   spec-keyed engine cache — hits/misses/evictions, build and
-  first-dispatch (≈ compile) wall time, per-dispatch spans — queryable via
-  ``obs.cache_stats()``; plus ``obs.span(name)`` for ad-hoc regions (the
-  benchmark harness' timer) and ``obs.profile(label)`` for
-  ``jax.profiler`` traces.
+  first-dispatch (≈ compile) wall time, per-dispatch time — is queryable
+  via ``obs.cache_stats()``; ``obs.span(name)`` times ad-hoc regions (the
+  benchmark harness' timer).
 
 - **Records** (``repro.obs.records`` / ``repro.obs.report``): ``run(spec,
   envs, record=True)`` (also ``sweep``/``compare_techniques``) appends a
@@ -47,8 +55,9 @@ from . import records, report as report_mod, spans, tap as tap_mod
 from .records import (load_records, make_record, run_info, spec_fields,
                       spec_key, write_record)
 from .report import report, sparkline
-from .spans import (Span, cache_stats, engine_key_str, engine_stat,
-                    note_bench, profile, reset_stats, span)
+from .spans import (Request, Span, cache_stats, count, engine_key_str,
+                    engine_stat, profile, request, requests, reset_stats,
+                    span)
 from .spans import spans as all_spans
 from .tap import (KNOWN_TAPS, TapBuffer, TapEvent, active_taps, capture,
                   clear_events, disable_taps, enable_taps, enabled, events,
@@ -58,8 +67,9 @@ __all__ = [
     "tap", "taps", "capture", "events", "ring", "clear_events",
     "enable_taps", "disable_taps", "enabled", "active_taps", "tracing",
     "KNOWN_TAPS", "TapBuffer", "TapEvent",
-    "span", "all_spans", "Span", "cache_stats", "engine_stat",
-    "engine_key_str", "reset_stats", "note_bench", "profile",
+    "span", "all_spans", "Span", "request", "requests", "Request", "count",
+    "cache_stats", "engine_stat", "engine_key_str",
+    "reset_stats", "profile",
     "make_record", "write_record", "load_records", "run_info",
     "spec_fields", "spec_key",
     "report", "sparkline",

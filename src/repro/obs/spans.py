@@ -1,21 +1,35 @@
-"""Compile- and dispatch-span accounting for the compiled engines.
+"""Request-scoped spans and compile/dispatch accounting for the engines.
 
 Before this module, compile time was silently folded into wall time and the
 spec-keyed compile cache in ``repro.core.experiment`` was opaque — a perf
 number could mean "fast engine" or "you hit the cache" and nothing could
-tell them apart. Three pieces:
+tell them apart. Four pieces:
 
-- ``span(name)`` — a ``with``-able wall-clock span (``.seconds`` after
-  exit). The benchmark harness' ``Timer`` is this span under another name,
-  so bench rows and engine telemetry share one timing code path
-  (``note_bench`` records the emitted rows here too).
+- ``span(name)`` — a ``with``-able wall-clock span (``start``/``end`` on
+  ``time.perf_counter``, ``.seconds`` after exit). Spans nest into a tree:
+  each carries its own ``id``, its ``parent``'s id and the ``request`` id
+  of the public call it ran under. The current span lives in a
+  ``contextvars.ContextVar``, so a worker thread started under
+  ``contextvars.copy_context()`` keeps its spans under their request. Every
+  span also enters ``jax.profiler.TraceAnnotation(name)``, so it lands on
+  the host plane of any ``jax.profiler`` trace taken around it, beside the
+  device ops. The benchmark harness' ``Timer`` is this span under another
+  name.
+- requests — ``request(name)`` opens the root span of one public call
+  (``api.run``, ``api.sweep``); inside an open request it opens nothing, so
+  a nested public call stays part of its caller's request. ``count(name,
+  n)`` adds to the current request's counters (``rows``, ``fleet_hours``,
+  ``dispatches``, ``fetches``). ``requests(last=n)`` returns the last
+  ``n`` finished requests with their counters and the self time of each
+  child span name.
 - engine-cache accounting — ``repro.core.experiment._compiled`` reports
   every lookup (``engine_lookup``), wraps every artifact's dispatch
-  (``instrument_dispatch``: per-call wall time, first-dispatch time ≈
-  trace+XLA-compile+run, and the trace-time tap pinning), and reports
-  evictions (``note_eviction``, fired by ``register_technique(overwrite=
-  True)`` / ``unregister_technique``). ``cache_stats()`` is the queryable
-  view; a test asserts the taps-off path adds zero compiles.
+  (``instrument_dispatch``: an ``engine.dispatch`` span per call, the
+  first-dispatch time ≈ trace+XLA-compile+run, and the trace-time tap
+  pinning), and reports evictions (``note_eviction``, fired by
+  ``register_technique(overwrite=True)`` / ``unregister_technique``).
+  ``cache_stats()`` is the queryable view; a test asserts the taps-off path
+  adds zero compiles.
 - ``profile(label)`` — a ``jax.profiler`` trace dropped under
   ``runs/profiles/<label>`` for kernel-level work (the ROADMAP's Pallas
   item); raises where the profiler cannot start, so a run that asked for a
@@ -28,39 +42,138 @@ landed in its buffer before the engine returns — numerics are unaffected.
 from __future__ import annotations
 
 import collections
+import contextlib
+import contextvars
 import dataclasses
+import itertools
+import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Callable, Dict, List, Optional
 
+from jax.profiler import TraceAnnotation
+
 from . import tap as _tap
 
 SPAN_CAPACITY = 4096
+REQUEST_CAPACITY = 1024
+
+_ids = itertools.count(1)
+# a request's spans may close on a worker thread (``call_with_timeout``)
+_LOCK = threading.Lock()
+_CURRENT: contextvars.ContextVar[Optional["Span"]] = contextvars.ContextVar(
+    "repro_obs_span", default=None)
 
 
 @dataclasses.dataclass
-class Span:
-    """One timed region. ``seconds`` is set when the region exits."""
+class Request:
+    """One public call: its root span's name and interval, its counters,
+    the self time of each child span name (``spans``) and of the root
+    itself (``self_s``)."""
+    id: int
     name: str
-    seconds: float = 0.0
-    meta: Dict[str, Any] = dataclasses.field(default_factory=dict)
-    _t0: float = dataclasses.field(default=0.0, repr=False)
+    start: float = 0.0
+    end: float = 0.0
+    self_s: float = 0.0
+    counters: Dict[str, float] = dataclasses.field(default_factory=dict)
+    spans: Dict[str, float] = dataclasses.field(default_factory=dict)
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
+
+
+class Span:
+    """One timed region of the span tree. ``start``/``end`` are
+    ``time.perf_counter`` readings; ``parent`` and ``request`` are ids
+    (``None`` outside any span / request)."""
+    __slots__ = ("name", "meta", "id", "parent", "request", "start", "end",
+                 "_root", "_up", "_req", "_child_s", "_token", "_annotation")
+
+    def __init__(self, name: str, meta: Optional[Dict[str, Any]] = None,
+                 _root: bool = False):
+        self.name = name
+        self.meta = {} if meta is None else meta
+        self.id = 0
+        self.parent: Optional[int] = None
+        self.request: Optional[int] = None
+        self.start = self.end = 0.0
+        self._root = _root
+        self._up: Optional[Span] = None
+        self._req: Optional[Request] = None
+        self._child_s = 0.0
+
+    def __repr__(self) -> str:
+        return (f"Span({self.name!r}, id={self.id}, parent={self.parent}, "
+                f"request={self.request}, seconds={self.seconds:.6f})")
+
+    @property
+    def seconds(self) -> float:
+        return self.end - self.start
 
     def __enter__(self) -> "Span":
-        self._t0 = time.perf_counter()
+        up = _CURRENT.get()
+        self.id = next(_ids)
+        if up is not None:
+            self._up = up
+            self.parent = up.id
+            self._req = up._req
+        if self._root:
+            self._req = Request(id=self.id, name=self.name)
+        if self._req is not None:
+            self.request = self._req.id
+        self._annotation = TraceAnnotation(self.name)
+        self._annotation.__enter__()
+        self._token = _CURRENT.set(self)
+        self.start = time.perf_counter()
         return self
 
     def __exit__(self, *exc) -> None:
-        self.seconds = time.perf_counter() - self._t0
+        self.end = time.perf_counter()
+        _CURRENT.reset(self._token)
+        self._annotation.__exit__(*exc)
+        self._token = self._annotation = None
+        seconds = self.end - self.start
+        req = self._req
+        with _LOCK:
+            if self._up is not None:
+                self._up._child_s += seconds
+            if req is not None:
+                own = seconds - self._child_s
+                if self._root:
+                    req.start, req.end, req.self_s = self.start, self.end, own
+                    _REQUESTS.append(req)
+                else:
+                    req.spans[self.name] = req.spans.get(self.name, 0.0) + own
         _SPANS.append(self)
 
 
 _SPANS: collections.deque = collections.deque(maxlen=SPAN_CAPACITY)
+_REQUESTS: collections.deque = collections.deque(maxlen=REQUEST_CAPACITY)
 
 
 def span(name: str, **meta) -> Span:
     """``with obs.span("phase") as s: ...`` — then read ``s.seconds``."""
-    return Span(name=name, meta=meta)
+    return Span(name, meta)
+
+
+def request(name: str, **meta):
+    """The root span of one public call (``with obs.request("api.run")``);
+    inside an open request it opens nothing and yields the current span."""
+    cur = _CURRENT.get()
+    if cur is not None and cur._req is not None:
+        return contextlib.nullcontext(cur)
+    return Span(name, meta, _root=True)
+
+
+def count(name: str, n: float = 1) -> None:
+    """Add ``n`` to counter ``name`` of the current request (no-op outside
+    any request)."""
+    cur = _CURRENT.get()
+    if cur is not None and cur._req is not None:
+        c = cur._req.counters
+        with _LOCK:
+            c[name] = c.get(name, 0) + n
 
 
 def spans(name: Optional[str] = None) -> List[Span]:
@@ -68,12 +181,12 @@ def spans(name: Optional[str] = None) -> List[Span]:
     return out if name is None else [s for s in out if s.name == name]
 
 
-def note_bench(name: str, seconds: float, derived: str = "") -> None:
-    """Record one benchmark row as a span (the bench harness' ``emit``
-    routes through here, so ``BENCH_*.json`` rows and engine spans are the
-    same measurements)."""
-    _SPANS.append(Span(name=name, seconds=seconds,
-                       meta={"kind": "bench", "derived": derived}))
+def requests(last: Optional[int] = None) -> List[Request]:
+    """The finished requests, oldest first; ``last=n`` keeps the last n."""
+    out = list(_REQUESTS)
+    if last is None:
+        return out
+    return out[len(out) - last:] if last > 0 else []
 
 
 # ---------------------------------------------------------------------------
@@ -165,11 +278,11 @@ def instrument_dispatch(key: tuple, fn: Callable) -> Callable:
 
     def dispatch(*args, **kwargs):
         st = _stat(key)
-        t0 = time.perf_counter()
-        with _tap.tracing(taps):
+        with span("engine.dispatch") as s, _tap.tracing(taps):
             out = fn(*args, **kwargs)
             jax.block_until_ready(out)
-        dt = time.perf_counter() - t0
+        count("dispatches")
+        dt = s.seconds
         st.dispatches += 1
         st.dispatch_s += dt
         st.last_dispatch_s = dt
@@ -199,12 +312,13 @@ def engine_stat(key: tuple) -> Optional[Dict[str, Any]]:
 
 
 def reset_stats() -> None:
-    """Zero the accounting (counters/spans). Does NOT touch the live
-    compiled artifacts: keys still cached keep hitting, so post-reset
+    """Zero the accounting (counters/spans/requests). Does NOT touch the
+    live compiled artifacts: keys still cached keep hitting, so post-reset
     numbers stay truthful about what actually compiled."""
     global _evictions
     _engine.clear()
     _SPANS.clear()
+    _REQUESTS.clear()
     _evictions = 0
 
 

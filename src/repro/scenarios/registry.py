@@ -17,6 +17,7 @@ from __future__ import annotations
 
 from typing import Any, Callable, Dict, Mapping, NamedTuple, Optional, Tuple
 
+from .. import obs
 from ..dcsim.env import EnvParams
 
 Transform = Callable[[EnvParams], EnvParams]
@@ -95,9 +96,15 @@ class Scenario(NamedTuple):
 
 
 def apply_all(env: EnvParams, scenarios) -> EnvParams:
-    """Apply a sequence of Scenario specs (or transforms) in order."""
+    """Apply a sequence of Scenario specs (or transforms) in order, each in
+    an ``obs`` span ``scenario.<name>``."""
     for s in scenarios:
-        env = s.apply(env) if isinstance(s, Scenario) else s(env)
+        if isinstance(s, Scenario):
+            with obs.span("scenario." + s.name):
+                env = s.apply(env)
+        else:
+            with obs.span("scenario." + getattr(s, "__name__", "transform")):
+                env = s(env)
     return env
 
 

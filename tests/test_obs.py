@@ -4,6 +4,7 @@ the engine's own per-epoch metrics), the compile-cache accounting tracks
 hits/misses/evictions, run records round-trip with full provenance, and the
 scoreboard renders from records alone."""
 import os
+import time
 
 import jax
 import jax.numpy as jnp
@@ -150,15 +151,180 @@ def test_span_records_wall_time_into_the_stream():
 
 
 def test_bench_timer_is_an_obs_span():
-    from benchmarks.common import Timer, emit
+    from benchmarks.common import Timer
     with Timer() as tm:
         sum(range(1000))
     assert isinstance(tm, obs.Span) and tm.seconds > 0.0
-    rows = ["header"]
-    emit(rows, "test/bench_row", 0.5, "derived=1")
-    bench = [s for s in obs.all_spans("test/bench_row")
-             if s.meta.get("kind") == "bench"]
-    assert bench and bench[-1].seconds == 0.5
+    assert obs.all_spans("bench")[-1] is tm
+
+
+def test_span_tree_carries_request_parent_and_self_time():
+    with obs.request("api.test") as root:
+        with obs.span("a") as a:
+            with obs.span("b") as b:
+                time.sleep(0.002)
+            obs.count("rows", 2)
+        with obs.span("a") as a2:
+            obs.count("rows")
+    assert root.parent is None and root.request == root.id
+    assert (a.parent, b.parent, a2.parent) == (root.id, a.id, root.id)
+    assert {a.request, b.request, a2.request} == {root.id}
+    assert root.start <= a.start <= b.start <= b.end <= a.end <= a2.start
+    req = obs.requests(last=1)[0]
+    assert (req.id, req.name) == (root.id, "api.test")
+    assert req.counters == {"rows": 3}
+    assert req.spans["b"] == pytest.approx(b.seconds)
+    assert req.spans["a"] == pytest.approx(a.seconds - b.seconds + a2.seconds)
+    assert req.self_s == pytest.approx(root.seconds - a.seconds - a2.seconds)
+    assert req.self_s + sum(req.spans.values()) == pytest.approx(root.seconds)
+    # outside any request a span has none, and counters go nowhere
+    with obs.span("free") as free:
+        obs.count("rows")
+    assert free.request is None and free.parent is None
+    assert obs.requests(last=1)[0] is req
+
+
+def test_one_request_per_outermost_public_call():
+    from repro.core import sweep
+    last = obs.requests(last=1)
+    before = last[0].id if last else 0
+    run(SPEC, ENV)
+    with obs.request("api.outer"):
+        run(SPEC, ENV)    # nested public calls join the outer request
+        run(SPEC, ENV)
+    sweep(SPEC, {"wan_degradation": (1.0, 2.0)}, base_env=ENV)
+    reqs = [r for r in obs.requests() if r.id > before]
+    assert [r.name for r in reqs] == ["api.run", "api.outer", "api.sweep"]
+    assert reqs[1].counters["dispatches"] == 2
+    assert {"engine.inputs", "engine.dispatch", "engine.fetch",
+            "engine.format"} <= set(reqs[0].spans)
+    assert {"sweep.grid", "scenario.wan_degradation", "sweep.stack",
+            "engine.inputs", "engine.dispatch", "engine.fetch",
+            "engine.format"} <= set(reqs[2].spans)
+    roots = [s for s in obs.all_spans() if s.id in {r.id for r in reqs}]
+    assert [s.parent for s in roots] == [None] * 3
+
+
+def test_requests_last_n_carry_the_calls_counters():
+    from repro.core import sweep
+    one = run(SPEC, ENV)
+    batch = run(SPEC.replace(engine="batched", seeds=(0, 1, 2)),
+                [ENV, ENV, ENV])
+    grid = sweep(SPEC, {"wan_degradation": (1.0, 2.0),
+                        "sla_tighten": (1.0, 0.8)}, base_env=ENV)
+    reqs = obs.requests(last=3)
+    assert [r.name for r in reqs] == ["api.run", "api.run", "api.sweep"]
+    h = SPEC.hours
+    assert [r.counters["rows"] for r in reqs] == [1, 3, 4]
+    assert [r.counters["fleet_hours"] for r in reqs] == [h, 3 * h, 4 * h]
+    assert [r.counters["dispatches"] for r in reqs] == [1, 1, 1]
+    assert [r.counters["fetches"] for r in reqs] == [
+        len(one["per_epoch"][0]) - 1, len(batch["per_epoch"]),
+        len(grid["results"]["fd"]["per_epoch"])]
+    assert obs.requests(last=0) == []
+    assert obs.requests(last=2) == reqs[1:]
+
+
+def test_call_with_timeout_keeps_spans_under_the_callers_request(tmp_path):
+    from repro.core import sweep
+    from repro.faults.resume import call_with_timeout
+
+    def work():
+        with obs.span("worker") as w:
+            obs.count("rows")
+        return w
+
+    with obs.request("api.test") as root:
+        with obs.span("sweep.chunk") as chunk:
+            w = call_with_timeout(work, 30.0)
+    assert (w.parent, w.request) == (chunk.id, root.id)
+    req = obs.requests(last=1)[0]
+    assert req.counters == {"rows": 1} and "worker" in req.spans
+
+    # a journaled sweep with a chunk timeout: engine spans stay under
+    # their chunk, in the sweep's request
+    sweep(SPEC, {"wan_degradation": (1.0, 2.0, 3.0)}, base_env=ENV,
+          resume_dir=str(tmp_path / "journal"), chunk_points=2,
+          point_timeout_s=120.0)
+    req = obs.requests(last=1)[0]
+    assert req.name == "api.sweep"
+    assert req.counters["dispatches"] == 2 and req.counters["rows"] == 3
+    mine = [s for s in obs.all_spans() if s.request == req.id]
+    chunks = {s.id for s in mine if s.name == "sweep.chunk"}
+    dispatch = [s for s in mine if s.name == "engine.dispatch"]
+    assert len(chunks) == 2 and len(dispatch) == 2
+    assert {s.parent for s in dispatch} == chunks
+
+
+def test_worker_threads_lose_no_counts_or_span_time():
+    import contextvars
+    import sys
+    import threading
+
+    n = 2 * (os.cpu_count() or 1)     # more threads than cores
+    per = 3000 // n                   # all their spans stay in the ring
+
+    def work():
+        for _ in range(per):
+            with obs.span("w"):
+                obs.count("rows")
+
+    old = sys.getswitchinterval()
+    sys.setswitchinterval(1e-6)
+    try:
+        with obs.request("api.test") as root:
+            threads = [threading.Thread(target=contextvars.copy_context().run,
+                                        args=(work,))
+                       for _ in range(n)]
+            for th in threads:
+                th.start()
+            for th in threads:
+                th.join(timeout=60)
+            assert not any(th.is_alive() for th in threads)
+    finally:
+        sys.setswitchinterval(old)
+    req = obs.requests(last=1)[0]
+    assert req.id == root.id
+    assert req.counters["rows"] == per * n
+    mine = [s for s in obs.all_spans("w") if s.request == root.id]
+    assert req.spans["w"] == pytest.approx(sum(s.seconds for s in mine))
+    assert req.self_s == pytest.approx(root.seconds - req.spans["w"])
+
+
+def test_span_lands_on_the_host_plane_of_a_profiler_trace(tmp_path):
+    from jax.profiler import ProfileData
+
+    with obs.profile("spans", logdir=str(tmp_path)) as p:
+        with jax.profiler.TraceAnnotation("test.outer"):
+            with obs.span("test.inner"):
+                jnp.ones(4).block_until_ready()
+    path = [os.path.join(d, f) for d, _, fs in os.walk(p) for f in fs
+            if f.endswith(".xplane.pb")][0]
+    events = {}
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name.startswith("/host:"):
+            for line in plane.lines:
+                for ev in line.events:
+                    events[ev.name] = (ev.start_ns,
+                                       ev.start_ns + ev.duration_ns)
+    assert "test.inner" in events and "test.outer" in events
+    (o0, o1), (i0, i1) = events["test.outer"], events["test.inner"]
+    assert o0 <= i0 <= i1 <= o1
+
+
+def test_named_scopes_reach_the_compiled_hlo():
+    from repro import faults as FL
+    key, state0 = X._day_inputs(ENV, "fd", "carbon", 0, True, FD_CFG)
+    peak0 = jnp.zeros((E.num_dcs(ENV),))
+    plain = X._day_core("fd", "carbon", 2, FD_CFG)
+    txt = jax.jit(plain).lower(ENV, key, peak0, state0).compile().as_text()
+    assert "solver/" in txt and "simulate/" in txt
+    assert "failover/" not in txt
+    faulted = X._day_core("fd", "carbon", 2, FD_CFG, faulted=True)
+    trace = FL.random_trace(ENV, seed=0)
+    txt = jax.jit(faulted).lower(ENV, key, peak0, state0,
+                                 trace).compile().as_text()
+    assert "solver/" in txt and "failover/" in txt and "guard/" in txt
 
 
 def test_cache_stats_dispatch_accounting():

@@ -430,6 +430,24 @@ def _day_inputs(env, technique, objective, seed, pretrain, cfg,
     return key, t.init_state(kp, env, objective, cfg, routed, pretrain)
 
 
+_split_seeds = jax.jit(jax.vmap(
+    lambda s: jax.random.split(jax.random.PRNGKey(s))[1]))
+
+
+def _row_keys(seeds: Sequence[int]) -> jnp.ndarray:
+    """Each row's day key, ``jax.random.split(jax.random.PRNGKey(s))[1]``
+    (``run_day``'s split), for all rows in one compiled call.
+
+    Inside a program the seed is an int32, so only seeds in [0, 2**31) give
+    the eager key there; any other seed list takes the eager expression.
+    """
+    if all(isinstance(s, (int, np.integer)) and 0 <= s < 2 ** 31
+           for s in seeds):
+        return _split_seeds(np.asarray(seeds, dtype=np.int32))
+    return jnp.stack([jax.random.split(jax.random.PRNGKey(s))[1]
+                      for s in seeds])
+
+
 def _totals_keys(present) -> Tuple[str, ...]:
     """The result's totals keys: the invariant ``_TOTAL_KEYS`` plus any
     degradation metrics the engine actually emitted (faulted/guarded
@@ -656,7 +674,7 @@ def _run_batched(spec, envs, solver_state0, shard, faults=None):
             envs = [envs]  # single env == batch of one (compare_techniques parity)
         if isinstance(envs, E.EnvParams):
             env_b, n = envs, int(envs.er.shape[0])
-            env0 = jax.tree_util.tree_map(lambda x: x[0], envs)
+            env0 = E.first_row(envs)
         else:
             envs = list(envs)
             env_b, n = E.stack_envs(envs), len(envs)
@@ -665,11 +683,11 @@ def _run_batched(spec, envs, solver_state0, shard, faults=None):
         if len(seeds) != n:
             raise ValueError(f"{len(seeds)} seeds for {n} scenario-days")
 
-        # per-day keys split exactly as run_day splits them; gt-drl
-        # pretrains ONCE on the first seed's pretrain key (deploy-once
-        # semantics)
-        keys = jnp.stack([jax.random.split(jax.random.PRNGKey(s))[1]
-                          for s in seeds])
+        # per-day keys split exactly as run_day splits them (padded rows
+        # repeat the last); gt-drl pretrains ONCE on the first seed's
+        # pretrain key (deploy-once semantics)
+        pad = (-n) % jax.device_count() if shard else 0
+        keys = _row_keys(seeds + seeds[-1:] * pad)
         _, state0 = _day_inputs(env0, spec.technique, spec.objective,
                                 seeds[0], spec.pretrain, spec.cfg,
                                 solver_state0, spec.routed)
@@ -682,11 +700,8 @@ def _run_batched(spec, envs, solver_state0, shard, faults=None):
                 f"stacked FaultTrace has {int(faults.avail_mult.shape[0])} "
                 f"rows for {n} scenario-days")
         trace = (faults,) if faulted else ()
-        pad = (-n) % jax.device_count() if shard else 0
         if pad:
             env_b = E.pad_env_batch(env_b, n + pad)
-            keys = jnp.concatenate(
-                [keys, jnp.broadcast_to(keys[-1:], (pad,) + keys.shape[1:])])
             if stacked:  # pad the trace rows alongside their envs
                 trace = (jax.tree_util.tree_map(
                     lambda x: jnp.concatenate(
@@ -712,7 +727,7 @@ def _run_month(spec, envs, peak_state0, solver_state0):
             env0, env_days = envs, E.tile_env(envs, n)
         elif isinstance(envs, E.EnvParams):
             n = int(envs.er.shape[0])
-            env0 = jax.tree_util.tree_map(lambda x: x[0], envs)
+            env0 = E.first_row(envs)
             env_days = envs
         else:
             envs = [e if isinstance(e, E.EnvParams) else e[1] for e in envs]
@@ -720,9 +735,7 @@ def _run_month(spec, envs, peak_state0, solver_state0):
         if days is not None and int(days) != n:
             raise ValueError(f"days={days} but {n} per-day envs were given")
 
-        keys = jnp.stack(
-            [jax.random.split(jax.random.PRNGKey(spec.seed + d))[1]
-             for d in range(n)])
+        keys = _row_keys([spec.seed + d for d in range(n)])
         _, state0 = _day_inputs(env0, spec.technique, spec.objective,
                                 spec.seed, spec.pretrain, spec.cfg,
                                 solver_state0, spec.routed)

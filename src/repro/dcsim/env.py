@@ -74,6 +74,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .. import obs
 from . import capability, latency, renewables, topology
 from . import workload as _workload
 from .topology import CRAC_MAX_W, CRAC_PER_DC, NETWORK_PRICE
@@ -221,14 +222,33 @@ def build_env(
     )
 
 
+@jax.jit
+def stack_trees(trees):
+    """Stack a list of same-structure pytrees leaf-wise in one compiled call.
+
+    One program per row count and leaf shapes; the values are those of an
+    eager ``jnp.stack`` per leaf, which costs a dispatch per row and leaf.
+    """
+    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *trees)
+
+
+@jax.jit
+def first_row(tree):
+    """Row 0 of every leaf of a stacked pytree, in one compiled call."""
+    return jax.tree_util.tree_map(lambda x: x[0], tree)
+
+
 def stack_envs(envs) -> EnvParams:
     """Stack same-shape envs leaf-wise into one batched EnvParams.
 
     The leading axis is a scenario-day (or calendar-day) batch: vmap over it
     for fleet evaluation (``schedulers.run_days_batched``) or scan over it
-    for month-scale episodes (``schedulers.run_month``).
+    for month-scale episodes (``schedulers.run_month``). Counts the rows as
+    ``stacked_rows`` on the current ``obs`` request.
     """
-    return jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *list(envs))
+    envs = list(envs)
+    obs.count("stacked_rows", len(envs))
+    return stack_trees(envs)
 
 
 def tile_env(env: EnvParams, n: int) -> EnvParams:

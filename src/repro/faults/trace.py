@@ -39,6 +39,8 @@ from typing import NamedTuple, Optional, Sequence
 import jax.numpy as jnp
 import numpy as np
 
+from ..dcsim.env import stack_trees
+
 HOURS = 24
 
 
@@ -173,8 +175,7 @@ def stack_traces(traces: Sequence[FaultTrace]) -> FaultTrace:
     shapes = {t.avail_mult.shape for t in traces}
     if len(shapes) != 1:
         raise ValueError(f"traces disagree on (D, hours): {sorted(shapes)}")
-    return FaultTrace(*(jnp.stack([getattr(t, f) for t in traces])
-                        for f in FaultTrace._fields))
+    return stack_trees(traces)
 
 
 _KINDS = ("dc_crash", "brownout", "wan_partition", "telemetry_dropout")

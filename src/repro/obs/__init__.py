@@ -27,9 +27,9 @@ change no output.
   ``scenario.<transform>``, ``sweep.stack``, ``engine.inputs``,
   ``engine.dispatch``, ``engine.fetch``, ``engine.format``) and whose
   counters say what it did (``rows``, ``fleet_hours``, ``dispatches``,
-  ``fetches``): ``obs.requests(last=n)``. Each span is also a
-  ``jax.profiler.TraceAnnotation``, so it shows on the host plane of an
-  ``obs.profile(label)`` trace. Compile-cache accounting for the
+  ``fetches``, ``stacked_rows``): ``obs.requests(last=n)``. Each span is
+  also a ``jax.profiler.TraceAnnotation``, so it shows on the host plane
+  of an ``obs.profile(label)`` trace. Compile-cache accounting for the
   spec-keyed engine cache — hits/misses/evictions, build and
   first-dispatch (≈ compile) wall time, per-dispatch time — is queryable
   via ``obs.cache_stats()``; ``obs.span(name)`` times ad-hoc regions (the

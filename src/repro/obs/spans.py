@@ -19,9 +19,9 @@ tell them apart. Four pieces:
   (``api.run``, ``api.sweep``); inside an open request it opens nothing, so
   a nested public call stays part of its caller's request. ``count(name,
   n)`` adds to the current request's counters (``rows``, ``fleet_hours``,
-  ``dispatches``, ``fetches``). ``requests(last=n)`` returns the last
-  ``n`` finished requests with their counters and the self time of each
-  child span name.
+  ``dispatches``, ``fetches``, ``stacked_rows``). ``requests(last=n)``
+  returns the last ``n`` finished requests with their counters and the
+  self time of each child span name.
 - engine-cache accounting — ``repro.core.experiment._compiled`` reports
   every lookup (``engine_lookup``), wraps every artifact's dispatch
   (``instrument_dispatch``: an ``engine.dispatch`` span per call, the

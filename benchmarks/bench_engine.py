@@ -1,12 +1,11 @@
 """Evaluation-pipeline throughput: the paper's ``compare_techniques``
 protocol (hour-loop reference vs one-compile batched engine), GT-DRL
-best-response round cost (full-width masked vmap vs gathered half dispatch),
-and month-scale episodes.
+best-response round cost (gathered half dispatch), and month-scale
+episodes.
 
 Rows (name, us_per_call, derived):
   engine/compare_loop_<t>     us per 5-env suite evaluation, loop reference
   engine/compare_batched_<t>  us per 5-env suite evaluation; speedup derived
-  engine/gtdrl_round_masked   us per game round, full-width masked dispatch
   engine/gtdrl_round_half     us per game round, I/2 gathered dispatch
   engine/month_day_<t>        us per simulated day inside run_month
   engine/day_scan_fd_cost     us per compiled day, plain cost objective
@@ -46,7 +45,6 @@ Rows (name, us_per_call, derived):
 """
 from __future__ import annotations
 
-import dataclasses
 import functools
 
 import jax
@@ -94,23 +92,18 @@ def run(rows):
              f"envs={n};speedup_vs_loop={loop_s / max(tm.seconds, 1e-9):.0f}x;"
              f"mean={res_b[t]['mean']:.0f}")
 
-    # -- GT-DRL round cost: masked full-width vmap vs gathered half dispatch -
+    # -- GT-DRL round cost: gathered half dispatch ----------------------------
     key = jax.random.PRNGKey(0)
     ctx = GameContext(env=env, tau=jnp.int32(12), objective="carbon")
     peak = jnp.zeros((E.num_dcs(env),))
-    round_times = {}
-    for impl in ("masked", "gather"):
-        cfg = dataclasses.replace(GTDRL_BENCH, half_update=impl)
-        agents = gt_drl.init_agents(key, env, cfg)
-        fn = jax.jit(functools.partial(gt_drl.solve_epoch, cfg=cfg))
-        jax.block_until_ready(fn(key, agents, ctx, peak))  # warm
-        with Timer() as tm:
-            jax.block_until_ready(fn(key, agents, ctx, peak))
-        round_times[impl] = tm.seconds / cfg.rounds
-    emit(rows, "engine/gtdrl_round_masked", round_times["masked"],
-         f"rounds={GTDRL_BENCH.rounds};players={E.num_players(env)}")
-    emit(rows, "engine/gtdrl_round_half", round_times["gather"],
-         f"speedup_vs_masked={round_times['masked'] / max(round_times['gather'], 1e-9):.1f}x")
+    cfg = GTDRL_BENCH
+    agents = gt_drl.init_agents(key, env, cfg)
+    fn = jax.jit(functools.partial(gt_drl.solve_epoch, cfg=cfg))
+    jax.block_until_ready(fn(key, agents, ctx, peak))  # warm
+    with Timer() as tm:
+        jax.block_until_ready(fn(key, agents, ctx, peak))
+    emit(rows, "engine/gtdrl_round_half", tm.seconds / cfg.rounds,
+         f"rounds={cfg.rounds};players={E.num_players(env)}")
 
     # -- month-scale episodes: second-level scan threading the peak state ----
     days = 3 if QUICK else 7

@@ -43,7 +43,6 @@ class GTDRLConfig:
     state_mode: str = "strategy"    # strategy | env
     pretrain_iters: int = 60        # total (tau, joint) contexts seen offline
     pretrain_batch: int = 4         # contexts trained in parallel per step
-    half_update: str = "gather"     # gather (I/2 dispatch) | masked (reference)
 
 
 def _norm(x: jnp.ndarray) -> jnp.ndarray:
@@ -134,33 +133,48 @@ def _player_reward_closure(env, tau, objective, peak_state, joint_fracs, i, scal
     return fn
 
 
-def _one_player_round(key, agent, env, tau, objective, peak_state, joint, i, mode, ppo_cfg,
-                      polish_steps=30, polish_lr=0.4):
-    """PPO-improve player i against fixed others; return (agent, greedy row).
-
-    The player's strategy row is (D,) — or its (S, D) routing matrix in a
-    routed game (``joint`` is then the (S, I, D) tensor); the agent always
-    works in the flattened logit space and rows reshape at the boundary.
-    """
+def _player_game(env, tau, objective, peak_state, joint, i, mode, episodes):
+    """Player ``i``'s MDP against the fixed others: ``reward_of`` (logits ->
+    minus its objective over its objective at ``joint``), ``state_of``
+    (logits -> state) and ``state0_fn`` (key -> ``episodes`` start states
+    around its current row, with Dirichlet jitter)."""
     routed = joint.ndim == 3
-    shape = _row_shape(env, routed)
     proj = E.project_feasible_routed if routed else E.project_feasible
     base = jnp.abs(E.player_reward(
         env, proj(env, joint, tau), tau, peak_state, objective)[i]) + 1e-6
     reward_of = _player_reward_closure(env, tau, objective, peak_state, joint, i, base)
     state_of = _state_of(env, tau, i, mode, routed)
-    own_logits = jnp.log(joint[..., i, :] + 1e-9).reshape(-1)
 
     def state0_fn(k):
-        # start episodes around the current strategy with Dirichlet jitter
         alpha = joint[..., i, :] * 20.0 + 0.5
         fr = jax.random.dirichlet(
-            k, jnp.broadcast_to(alpha, (ppo_cfg.episodes,) + alpha.shape))
-        fr = fr.reshape(ppo_cfg.episodes, -1)
+            k, jnp.broadcast_to(alpha, (episodes,) + alpha.shape))
+        fr = fr.reshape(episodes, -1)
         if mode == "strategy":
             return fr
         ctxf = _ctx_features(env, tau, i, routed)
-        return jnp.concatenate([fr, jnp.broadcast_to(ctxf, (ppo_cfg.episodes, ctxf.shape[0]))], axis=1)
+        return jnp.concatenate([fr, jnp.broadcast_to(ctxf, (episodes, ctxf.shape[0]))], axis=1)
+
+    return reward_of, state_of, state0_fn
+
+
+def _one_player_round(key, agent, env, tau, objective, peak_state, joint, i, mode, ppo_cfg,
+                      polish_steps=30, polish_lr=0.4):
+    """PPO-improve player i against fixed others; return (agent, greedy row,
+    info).
+
+    The player's strategy row is (D,) — or its (S, D) routing matrix in a
+    routed game (``joint`` is then the (S, I, D) tensor); the agent always
+    works in the flattened logit space and rows reshape at the boundary.
+    ``info`` holds the proposals and their rewards (``cand_logits``,
+    ``cand_rewards``, ``finals``, ``final_rewards``), for the comparison
+    with the plain reference; the engines drop it.
+    """
+    routed = joint.ndim == 3
+    shape = _row_shape(env, routed)
+    reward_of, state_of, state0_fn = _player_game(
+        env, tau, objective, peak_state, joint, i, mode, ppo_cfg.episodes)
+    own_logits = jnp.log(joint[..., i, :] + 1e-9).reshape(-1)
 
     k_ppo, k_cand = jax.random.split(key)
     agent, info = ppo_improve(k_ppo, agent, state0_fn, state_of, reward_of, ppo_cfg)
@@ -171,15 +185,16 @@ def _one_player_round(key, agent, env, tau, objective, peak_state, joint, i, mod
     # proposal minimizes its own objective, never regressing below its current
     # row. This is the game-theoretic step; PPO supplies the proposal
     # distribution (paper §5.3: "the agent determines the optimal strategy").
-    state_now = state_of(own_logits)
-    mu = nets.actor_mean(agent.actor, state_now)
-    std = jnp.exp(jnp.clip(agent.actor["log_std"], -4.0, 1.0))
-    n_cand = 16
-    eps = jax.random.normal(k_cand, (n_cand,) + mu.shape)
-    cand_logits = jnp.concatenate(
-        [mu[None], own_logits[None], mu[None] + std * eps], axis=0)
-    rewards = jax.vmap(reward_of)(cand_logits)
-    best_logits = cand_logits[jnp.argmax(rewards)]
+    with jax.named_scope("select"):
+        state_now = state_of(own_logits)
+        mu = nets.actor_mean(agent.actor, state_now)
+        std = jnp.exp(jnp.clip(agent.actor["log_std"], -4.0, 1.0))
+        n_cand = 16
+        eps = jax.random.normal(k_cand, (n_cand,) + mu.shape)
+        cand_logits = jnp.concatenate(
+            [mu[None], own_logits[None], mu[None] + std * eps], axis=0)
+        cand_rewards = jax.vmap(reward_of)(cand_logits)
+        best_logits = cand_logits[jnp.argmax(cand_rewards)]
     # ... then the game's rapid best-reply refinement polishes BOTH the
     # policy's best proposal and the incumbent row, adopting whichever basin
     # wins (paper: GT-DRL "combin[es] the rapidness of a non-cooperative
@@ -196,11 +211,16 @@ def _one_player_round(key, agent, env, tau, objective, peak_state, joint, i, mod
         return out
 
     starts = jnp.stack([best_logits, own_logits])
-    polished = jax.vmap(run_polish)(starts)
-    finals = jnp.concatenate([polished, starts], axis=0)
-    final_rewards = jax.vmap(reward_of)(finals)
-    row = jax.nn.softmax(finals[jnp.argmax(final_rewards)].reshape(shape), axis=-1)
-    return agent, row
+    with jax.named_scope("polish"):
+        polished = jax.vmap(run_polish)(starts)
+    with jax.named_scope("select"):
+        finals = jnp.concatenate([polished, starts], axis=0)
+        final_rewards = jax.vmap(reward_of)(finals)
+        row = jax.nn.softmax(finals[jnp.argmax(final_rewards)].reshape(shape),
+                             axis=-1)
+    return agent, row, {"cand_logits": cand_logits,
+                        "cand_rewards": cand_rewards, "finals": finals,
+                        "final_rewards": final_rewards}
 
 
 def _run_players(keys, agents, idx, env, tau, objective, peak_state, joint, cfg):
@@ -224,47 +244,27 @@ def half_update(agents, joint, key_r, parity: int, ctx: GameContext,
     best-respond simultaneously (vmapped); the other half hold — sequential
     information flow at Jacobi's vmap efficiency.
 
-    ``cfg.half_update`` selects the implementation:
-
-    - ``"gather"`` (default): gather the active half's rows/agents, dispatch
-      ``_one_player_round`` for ceil(I/2) players only, scatter back — half
-      the per-round FLOPs of the full-width version.
-    - ``"masked"``: reference — dispatch all I players and discard the
-      inactive half's updates with a parity mask. Same results (the per-player
-      keys are identical), twice the work; kept for parity tests/benchmarks.
-
-    Both modes give each agent ceil(rounds) PPO updates per round. The
-    original implementation also trained the *inactive* half's agents each
-    half-step (two updates per round, against a stale joint, discarding only
-    their rows) — that extra compute is exactly what this restructure
-    removes, so gt-drl trajectories differ numerically from the seed commit.
+    The active half's rows and agents are gathered, ``_one_player_round``
+    runs for ceil(I/2) players only, and the results are scattered back, so
+    each agent gets one PPO update per round. Player ``i`` uses key
+    ``jax.random.split(key_r, I)[i]`` whichever half it is in. The plain
+    reference of this step is ``chipbench/reference_gtdrl.py``.
     """
     env = ctx.env
     i_n = E.num_players(env)
     routed = joint.ndim == 3
     keys = jax.random.split(key_r, i_n)
-    # vmapped rows arrive player-major ((n,) + row_shape); a routed joint is
-    # source-major (S, I, D), so scatters move the player axis back to -2
-    to_joint = (lambda rows: jnp.moveaxis(rows, 0, 1)) if routed else (lambda rows: rows)
-    if cfg.half_update == "gather":
-        idx = jnp.arange(parity, i_n, 2)
-        sub = jax.tree_util.tree_map(lambda x: x[idx], agents)
-        sub, rows = _run_players(keys[idx], sub, idx, env, ctx.tau,
-                                 ctx.objective, peak_state, joint, cfg)
-        agents = jax.tree_util.tree_map(
-            lambda full, new: full.at[idx].set(new), agents, sub)
-        return agents, joint.at[..., idx, :].set(to_joint(rows))
-    if cfg.half_update != "masked":
-        raise ValueError(f"unknown half_update {cfg.half_update!r}")
-    new_agents, rows = _run_players(keys, agents, jnp.arange(i_n), env, ctx.tau,
-                                    ctx.objective, peak_state, joint, cfg)
-    active = jnp.arange(i_n) % 2 == parity
+    idx = jnp.arange(parity, i_n, 2)
+    sub = jax.tree_util.tree_map(lambda x: x[idx], agents)
+    sub, rows, _ = _run_players(keys[idx], sub, idx, env, ctx.tau,
+                                ctx.objective, peak_state, joint, cfg)
     agents = jax.tree_util.tree_map(
-        lambda old, new: jnp.where(
-            active.reshape((i_n,) + (1,) * (new.ndim - 1)), new, old),
-        agents, new_agents)
-    mask = active[None, :, None] if routed else active[:, None]
-    return agents, jnp.where(mask, to_joint(rows), joint)
+        lambda full, new: full.at[idx].set(new), agents, sub)
+    # vmapped rows arrive player-major ((n,) + row_shape); a routed joint is
+    # source-major (S, I, D), so the scatter moves the player axis back to -2
+    if routed:
+        rows = jnp.moveaxis(rows, 0, 1)
+    return agents, joint.at[..., idx, :].set(rows)
 
 
 def solve_epoch(
@@ -368,8 +368,8 @@ def pretrain(
         tau = jax.random.randint(k1, (), 0, 24)
         joint = jax.random.dirichlet(k2, jnp.ones(joint_shape))
         keys = jax.random.split(k3, i_n)
-        agents, _ = _run_players(keys, agents, jnp.arange(i_n), env, tau,
-                                 objective, peak0, joint, cfg)
+        agents, _, _ = _run_players(keys, agents, jnp.arange(i_n), env, tau,
+                                    objective, peak0, joint, cfg)
         return agents
 
     def one(agents, key_s):

@@ -9,6 +9,13 @@ import jax.numpy as jnp
 
 Params = Dict[str, Any]
 
+# The configurations state float32. On a TPU a float32 matmul at the default
+# precision is one bfloat16 pass: the learner's batched forward passes then
+# miss a float32 reference by ~2.6e-3 of their magnitude, against 2.6e-5 at
+# HIGH (three bfloat16 passes) and 1.9e-7 at HIGHEST (six). HIGH costs a
+# gt-drl day 2.7 % on a TPU v5e, HIGHEST 6.2 % (PERF.md).
+PRECISION = jax.lax.Precision.HIGH
+
 
 def mlp_init(key, sizes: Sequence[int], out_scale: float = 0.01) -> Params:
     p: Params = {}
@@ -24,7 +31,7 @@ def mlp_init(key, sizes: Sequence[int], out_scale: float = 0.01) -> Params:
 def mlp_apply(p: Params, x: jnp.ndarray) -> jnp.ndarray:
     n = len(p) // 2
     for li in range(n):
-        x = x @ p[f"w{li}"] + p[f"b{li}"]
+        x = jnp.matmul(x, p[f"w{li}"], precision=PRECISION) + p[f"b{li}"]
         if li < n - 1:
             x = jnp.tanh(x)
     return x

@@ -148,9 +148,11 @@ def ppo_improve(
     def it(carry, key_i):
         ag = carry
         k1, k2 = jax.random.split(key_i)
-        ro = _rollout(k1, ag, state0_fn(k2), state_of, reward_of, cfg)
-        adv, ret = _gae(ro, cfg)
-        ag, losses = _update(ag, ro, adv, ret, cfg)
+        with jax.named_scope("ppo_rollout"):
+            ro = _rollout(k1, ag, state0_fn(k2), state_of, reward_of, cfg)
+        with jax.named_scope("ppo_update"):
+            adv, ret = _gae(ro, cfg)
+            ag, losses = _update(ag, ro, adv, ret, cfg)
         return ag, (jnp.mean(ro.rewards), losses["actor_loss"])
 
     agent, (rew, al) = jax.lax.scan(it, agent, jax.random.split(key, cfg.iters))

@@ -6,6 +6,8 @@ assert "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS",
     "tests must run without the dry-run's device-count override"
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
+# the plain references the benchmark keeps (``chipbench``) back some tests
+sys.path.insert(1, os.path.join(os.path.dirname(__file__), ".."))
 
 import pytest
 

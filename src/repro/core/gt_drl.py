@@ -29,6 +29,7 @@ import numpy as np
 from .. import obs
 from ..dcsim import env as E
 from . import networks as nets
+from . import sampling
 from .game import GameContext, SolveResult, player_rewards, uniform_fractions
 from .ppo import AgentState, PPOConfig, agent_init, average_agents, ppo_improve
 
@@ -137,7 +138,8 @@ def _player_game(env, tau, objective, peak_state, joint, i, mode, episodes):
     """Player ``i``'s MDP against the fixed others: ``reward_of`` (logits ->
     minus its objective over its objective at ``joint``), ``state_of``
     (logits -> state) and ``state0_fn`` (key -> ``episodes`` start states
-    around its current row, with Dirichlet jitter)."""
+    around its current row, with Dirichlet jitter: ``sampling.dirichlet_counted``,
+    the samples of ``jax.random.dirichlet``)."""
     routed = joint.ndim == 3
     proj = E.project_feasible_routed if routed else E.project_feasible
     base = jnp.abs(E.player_reward(
@@ -147,8 +149,10 @@ def _player_game(env, tau, objective, peak_state, joint, i, mode, episodes):
 
     def state0_fn(k):
         alpha = joint[..., i, :] * 20.0 + 0.5
-        fr = jax.random.dirichlet(
-            k, jnp.broadcast_to(alpha, (episodes,) + alpha.shape))
+        with jax.named_scope("starts"):
+            fr, counts = sampling.dirichlet_counted(
+                k, jnp.broadcast_to(alpha, (episodes,) + alpha.shape))
+        obs.tap("gt_drl/starts", counts)
         fr = fr.reshape(episodes, -1)
         if mode == "strategy":
             return fr

@@ -19,6 +19,9 @@ change no output.
   ``gt_drl/round``          value, best, delta — per best-response round
   ``gt_drl/ppo``            player, actor_loss, mean_reward — per PPO
                             improve call
+  ``gt_drl/starts``         residual, replayed — per PPO start draw: the
+                            gamma variates left after the first trial,
+                            and those that reached the exact loop
   ========================  ===================================================
 
 - **Spans** (``repro.obs.spans``): always on, about 2 µs each. Every

@@ -48,6 +48,7 @@ KNOWN_TAPS = (
     "game/nash_residual",   # game loop: best-reply residual probe
     "gt_drl/ppo",           # GT-DRL: per-player PPO actor/critic losses
     "gt_drl/round",         # GT-DRL: per-round best-response telemetry
+    "gt_drl/starts",        # GT-DRL: PPO start draws left after one trial
 )
 
 

@@ -24,6 +24,7 @@ import numpy as np
 
 from repro import compile_cache, obs
 from repro.core import ExperimentSpec, run
+from repro.core import experiment as X
 from repro.core.ddpg import DDPGConfig
 from repro.core.force_directed import FDConfig
 from repro.core.genetic import GAConfig
@@ -89,7 +90,7 @@ def main() -> None:
                               cfg=CFGS.get(t), taps=("engine/hour",))
         with obs.capture("engine/hour") as buf:
             res = run(spec, env, record=out)
-        st = obs.engine_stat(obs_engine_key(spec)) or {}
+        st = obs.engine_stat(X._engine_key(spec)) or {}
         print(f"  {t:6s} carbon={res['totals']['carbon_kg']:9.1f} kg  "
               f"events={len(buf.events)}  "
               f"compile={st.get('first_dispatch_s', 0.0):.2f}s  "
@@ -112,11 +113,6 @@ def main() -> None:
     stats = obs.cache_stats()
     print(f"engine cache: {stats['misses']} compiles, {stats['hits']} hits, "
           f"{stats['live_keys']} live artifacts")
-
-
-def obs_engine_key(spec):
-    from repro.core import experiment as X
-    return X._engine_key(spec)
 
 
 if __name__ == "__main__":

@@ -8,10 +8,10 @@ replaces that with:
 
 - ``ExperimentSpec`` — a frozen, hashable description of one evaluation
   (technique, objective, engine, routed, hours/days, seeds, solver cfg,
-  pretrain). Its *static* fields — the ones that change the compiled
-  program — key a single module-level compile cache, so the scan, batched,
-  sharded and month engines all share compiled artifacts no matter which
-  call site (or legacy shim) asks for them.
+  pretrain). The fields that change the compiled program, gathered into
+  one frozen ``EngineKey``, key a single module-level compile cache, so the
+  scan, batched, sharded and month engines all share compiled artifacts no
+  matter which call site (or legacy shim) asks for them.
 - ``run(spec, envs)`` — the façade. ``spec.engine`` selects the day scan,
   the hour-loop reference, the vmapped fleet engine or the month scan;
   ``shard=True`` additionally shards the batched engine's env axis across
@@ -78,9 +78,10 @@ ENGINES = ("scan", "loop", "batched", "month")
 
 @dataclasses.dataclass(frozen=True)
 class ExperimentSpec:
-    """One evaluation, declaratively. Frozen and hashable: the static fields
-    (``technique``, ``objective``, ``hours``, ``cfg``, ``routed``) key the
-    module compile cache; the rest (seeds, days, pretrain) only select
+    """One evaluation, declaratively. Frozen and hashable: the fields that
+    ``EngineKey`` also declares (``technique``, ``objective``, ``hours``,
+    ``cfg``, ``routed`` …) key the module compile cache, ``engine`` keys it
+    as the key's ``kind``; the rest (seeds, days, pretrain) only select
     runtime inputs.
 
     ``engine``: ``"scan"`` — one env, one jitted day; ``"loop"`` — the
@@ -143,24 +144,99 @@ class ExperimentSpec:
     def replace(self, **changes) -> "ExperimentSpec":
         return dataclasses.replace(self, **changes)
 
-    def static_key(self) -> Tuple[str, str, int, Any, bool, str, bool, str]:
-        """The compile-relevant fields, in ``_day_core`` argument order.
-
-        ``workload`` joins the key even though the engines only ever see
-        ``EnvParams``: two workloads legitimately differ in the task-type
-        count ``I`` (a shape, hence a retrace), and keeping their artifacts
-        under distinct keys makes the cache accounting
-        (``obs.engine_stat``) attribute compiles to the right workload.
-        """
-        return (self.technique, self.objective, self.hours, self.cfg,
-                self.routed, self.failover, self.guard, self.workload)
-
     def effective_taps(self) -> frozenset:
         """The tap set this spec's engines compile under: the spec's own
         ``taps`` when given, else the ambient ``obs.taps(...)`` state. Part
         of the compile key, so tapped and untapped artifacts coexist."""
         return (obs.active_taps() if self.taps is None
                 else frozenset(self.taps))
+
+
+# ---------------------------------------------------------------------------
+# the compile key
+# ---------------------------------------------------------------------------
+
+_KINDS = ("day", "batched", "sharded", "month")
+
+
+@dataclasses.dataclass(frozen=True)
+class EngineKey:
+    """THE compile key: every field that changes an engine's traced program,
+    declared once. ``_engine_key`` builds it from a spec and every layer
+    passes it around whole; ``str(key)`` labels it in ``obs.cache_stats()``.
+
+    ``kind`` is the spec's ``engine`` (``batched`` becomes ``sharded`` under
+    ``shard=True``). ``faulted`` cores take a ``FaultTrace``; ``fault_axis``
+    (batched/sharded only) gives that trace a leading env-batch axis, one
+    realized day of trouble per env row. ``taps`` is the tap set the
+    artifact traces under. ``workload`` keys the cache although the engines
+    only ever see ``EnvParams``: two workloads differ in the task-type count
+    ``I`` (a shape, hence a retrace), and distinct keys let the cache
+    accounting attribute compiles to the right workload.
+    """
+    kind: str
+    technique: str
+    objective: str
+    hours: int
+    cfg: Any
+    routed: bool
+    failover: str
+    guard: bool
+    workload: str
+    faulted: bool
+    fault_axis: bool
+    taps: frozenset
+
+    def __post_init__(self):
+        if self.kind not in _KINDS:
+            raise ValueError(f"unknown engine kind {self.kind!r}; "
+                             f"known: {_KINDS}")
+        if self.fault_axis and self.kind not in ("batched", "sharded"):
+            raise ValueError("a per-point (stacked) FaultTrace only makes "
+                             "sense on the batched/sharded engines; the day "
+                             "and month engines take one trace "
+                             f"(kind={self.kind!r})")
+        if self.faulted and self.kind == "month":
+            raise ValueError(
+                "the month engine does not take realized faults yet: a "
+                "FaultTrace describes one 24h day, and the month scan "
+                "threads days through a second-level carry; run faulted "
+                "days through the scan/batched engines")
+
+    def __str__(self) -> str:
+        """``kind:technique:objective:h<hours>:cfg=<...>:routed=<...>:
+        wl=<workload>:faults=<policy|off[/point]>:guard=<on|off>:taps=<...>``"""
+        cfg_s = "default" if self.cfg is None else type(self.cfg).__name__
+        taps_s = ",".join(sorted(self.taps)) if self.taps else "off"
+        faults_s = self.failover if self.faulted else "off"
+        if self.faulted and self.fault_axis:
+            faults_s += "/point"  # one trace per env row
+        return (f"{self.kind}:{self.technique}:{self.objective}:"
+                f"h{self.hours}:cfg={cfg_s}:routed={bool(self.routed)}:"
+                f"wl={self.workload}:faults={faults_s}:"
+                f"guard={'on' if self.guard else 'off'}:taps={taps_s}")
+
+
+def _engine_key(spec: ExperimentSpec, *, shard: bool = False,
+                faulted: bool = False, fault_axis: bool = False) -> EngineKey:
+    """The compile-cache key ``run`` uses for this spec (also the join key
+    for ``obs.engine_stat`` / run records).
+
+    ``failover`` is an execute-time policy: on unfaulted lookups it is
+    normalized to the default so a spec's policy choice never forks the
+    (identical) unfaulted artifact; ``fault_axis`` is likewise normalized
+    out of unfaulted keys.
+    """
+    kind = {"scan": "day", "batched": "sharded" if shard else "batched",
+            "month": "month"}.get(spec.engine)
+    if kind is None:
+        raise ValueError(f"engine {spec.engine!r} is not compiled")
+    return EngineKey(
+        kind=kind, technique=spec.technique, objective=spec.objective,
+        hours=spec.hours, cfg=spec.cfg, routed=spec.routed,
+        failover=spec.failover if faulted else FL.DEFAULT_POLICY,
+        guard=spec.guard, workload=spec.workload, faulted=faulted,
+        fault_axis=faulted and fault_axis, taps=spec.effective_taps())
 
 
 # ---------------------------------------------------------------------------
@@ -180,12 +256,7 @@ def _solver_step(technique: str, cfg) -> Callable:
     return bound
 
 
-@functools.lru_cache(maxsize=None)
-def _day_core(technique: str, objective: str, hours: int, cfg,
-              routed: bool = False, failover: str = FL.DEFAULT_POLICY,
-              guard: bool = False, workload: str = "aibench",
-              faulted: bool = False,
-              taps: frozenset = frozenset()) -> Callable:
+def _day_core(ek: EngineKey) -> Callable:
     """day(env, key, peak0, state0[, trace]) -> (peak, state, metrics dict).
 
     Pure and jit/vmap-friendly; the RNG key is split exactly as the
@@ -203,24 +274,23 @@ def _day_core(technique: str, objective: str, hours: int, cfg,
 
     ``taps`` only keys the cache: the ``obs.tap`` calls in the body check
     trace-time enablement themselves (the dispatch wrapper pins the active
-    set to this key's ``taps``), so a taps-off core lowers to exactly the
-    pre-obs program and a tapped core is a distinct artifact.
-
-    ``workload`` likewise only keys the cache (see ``static_key``): the body
-    is workload-agnostic — a derived llm env is just an ``EnvParams`` with a
+    set to the key's ``taps``), so a taps-off core lowers to exactly the
+    pre-obs program and a tapped core is a distinct artifact. ``workload``
+    likewise only keys the cache (see ``EngineKey``): the body is
+    workload-agnostic — a derived llm env is just an ``EnvParams`` with a
     different ``I``.
     """
-    del workload  # cache-key discriminator only
-    step = _solver_step(technique, cfg)
-    guard_on = guard or faulted
+    step = _solver_step(ek.technique, ek.cfg)
+    faulted = ek.faulted
+    guard_on = ek.guard or faulted
 
     # named scopes reach the compiled HLO's op_name metadata only, so a
     # device trace's ops can be put down to a layer of the hour
     def _body(env, trace, carry, tau):
         key, peak, state = carry
         key, ks = jax.random.split(key)
-        ctx = GameContext(env=env, tau=tau, objective=objective,
-                          routed=routed)
+        ctx = GameContext(env=env, tau=tau, objective=ek.objective,
+                          routed=ek.routed)
         with jax.named_scope("solver"):
             state, res = step(ks, state, ctx, peak)
         with jax.named_scope("nash_probe"):
@@ -233,7 +303,8 @@ def _day_core(technique: str, objective: str, hours: int, cfg,
             ar = fractions_to_ar(ctx, fr)
         if faulted:
             with jax.named_scope("failover"):
-                peak, m = FL.execute_hour(env, trace, peak, ar, tau, failover)
+                peak, m = FL.execute_hour(env, trace, peak, ar, tau,
+                                          ek.failover)
         else:
             with jax.named_scope("simulate"):
                 peak, m = E.step_epoch(env, peak, ar, tau)
@@ -250,13 +321,13 @@ def _day_core(technique: str, objective: str, hours: int, cfg,
         def day(env: E.EnvParams, key, peak0, state0, trace):
             (_, peak, state), ms = jax.lax.scan(
                 functools.partial(_body, env, trace), (key, peak0, state0),
-                taus(hours))
+                taus(ek.hours))
             return peak, state, ms
     else:
         def day(env: E.EnvParams, key, peak0, state0):
             (_, peak, state), ms = jax.lax.scan(
                 functools.partial(_body, env, None), (key, peak0, state0),
-                taus(hours))
+                taus(ek.hours))
             return peak, state, ms
 
     return day
@@ -289,46 +360,20 @@ def _sharded_batch(core: Callable, faulted: bool = False,
     return jax.jit(fn)
 
 
-_KINDS = ("day", "batched", "sharded", "month")
-
-
 @functools.lru_cache(maxsize=None)
-def _compiled_raw(kind: str, technique: str, objective: str, hours: int, cfg,
-                  routed: bool, failover: str, guard: bool, workload: str,
-                  faulted: bool, fault_axis: bool,
-                  taps: frozenset) -> Callable:
-    """THE compile cache: one jitted artifact per (engine kind, spec static
-    fields, failover/guard/faulted flags, workload, tap set), shared by
-    ``run``/``sweep`` and every legacy shim — no engine compiles per call
-    site anymore. Artifacts come back wrapped in the obs dispatch span
-    (per-call timing + trace-time tap pinning).
-
-    ``fault_axis`` (batched/sharded only): the FaultTrace carries a leading
-    env-batch axis — one realized day of trouble per env row — instead of
-    one trace shared by every row."""
-    key = (kind, technique, objective, hours, cfg, routed, failover, guard,
-           workload, faulted, fault_axis, taps)
-    if fault_axis and kind not in ("batched", "sharded"):
-        raise ValueError("a per-point (stacked) FaultTrace only makes sense "
-                         "on the batched/sharded engines; the day and month "
-                         f"engines take one trace (kind={kind!r})")
-    core = _day_core(technique, objective, hours, cfg, routed, failover,
-                     guard, workload, faulted, taps)
-    if kind == "day":
+def _build(key: EngineKey) -> Callable:
+    """One jitted artifact per ``EngineKey``, wrapped in the obs dispatch
+    span (per-call timing + trace-time tap pinning)."""
+    core = _day_core(key)
+    if key.kind == "day":
         fn = jax.jit(core)
-    elif kind == "batched":
+    elif key.kind == "batched":
         axes = (0, 0, None, None) + (
-            ((0 if fault_axis else None),) if faulted else ())
+            ((0 if key.fault_axis else None),) if key.faulted else ())
         fn = jax.jit(jax.vmap(core, in_axes=axes))
-    elif kind == "sharded":
-        fn = _sharded_batch(core, faulted, fault_axis)
-    elif kind == "month":
-        if faulted:
-            raise ValueError(
-                "the month engine does not take realized faults yet: a "
-                "FaultTrace describes one 24h day, and the month scan "
-                "threads days through a second-level carry; run faulted "
-                "days through the scan/batched engines")
+    elif key.kind == "sharded":
+        fn = _sharded_batch(core, key.faulted, key.fault_axis)
+    else:
         def month(env_days, keys, peak0, state0):
             def body(carry, x):
                 peak, state = carry
@@ -341,70 +386,39 @@ def _compiled_raw(kind: str, technique: str, objective: str, hours: int, cfg,
             return peak, state, ms, peaks
 
         fn = jax.jit(month)
-    else:
-        raise ValueError(f"unknown engine kind {kind!r}; known: {_KINDS}")
-    return obs.spans.instrument_dispatch(key, fn)
+    return obs.spans.instrument_dispatch(key, key.taps, fn)
 
 
-def _compiled(kind: str, technique: str, objective: str, hours: int, cfg,
-              routed: bool, failover: str = FL.DEFAULT_POLICY,
-              guard: bool = False, workload: str = "aibench",
-              faulted: bool = False, fault_axis: bool = False,
-              taps: frozenset = frozenset()) -> Callable:
-    """Front door to the compile cache: same artifact as ``_compiled_raw``
-    but every lookup/build is accounted in ``obs.cache_stats()``."""
-    key = (kind, technique, objective, hours, cfg, routed, failover, guard,
-           workload, faulted, fault_axis, taps)
-    hit = obs.spans.engine_lookup(key)
-    if hit:
-        return _compiled_raw(*key)
-    t0 = time.perf_counter()
-    fn = _compiled_raw(*key)
-    obs.spans.note_build(key, time.perf_counter() - t0)
-    return fn
+class _EngineCache:
+    """THE compile cache: ``_build``'s artifacts, shared by ``run``/``sweep``
+    and every legacy shim — no engine compiles per call site. Every lookup
+    and build is accounted in ``obs.cache_stats()``; ``cache_clear`` drops
+    every artifact and reports the eviction."""
+
+    cache_info = staticmethod(_build.cache_info)
+
+    def __call__(self, key: EngineKey) -> Callable:
+        if obs.spans.engine_lookup(key):
+            return _build(key)
+        t0 = time.perf_counter()
+        fn = _build(key)
+        obs.spans.note_build(key, time.perf_counter() - t0)
+        return fn
+
+    def cache_clear(self) -> None:
+        _build.cache_clear()
+        obs.spans.note_eviction()
 
 
-# the cache-introspection surface tests rely on (lru semantics preserved)
-_compiled.cache_info = _compiled_raw.cache_info
-
-
-def _engine_key(spec: ExperimentSpec, *, shard: bool = False,
-                faulted: bool = False, fault_axis: bool = False) -> tuple:
-    """The compile-cache key ``run`` uses for this spec (also the join key
-    for ``obs.engine_stat`` / run records).
-
-    ``failover`` is an execute-time policy: on unfaulted lookups it is
-    normalized to the default so a spec's policy choice never forks the
-    (identical) unfaulted artifact; ``fault_axis`` is likewise normalized
-    out of unfaulted keys.
-    """
-    kind = {"scan": "day", "batched": "sharded" if shard else "batched",
-            "month": "month"}.get(spec.engine)
-    if kind is None:
-        raise ValueError(f"engine {spec.engine!r} is not compiled")
-    technique, objective, hours, cfg, routed, failover, guard, workload = \
-        spec.static_key()
-    if not faulted:
-        failover = FL.DEFAULT_POLICY
-        fault_axis = False
-    return (kind, technique, objective, hours, cfg, routed, failover, guard,
-            workload, faulted, fault_axis, spec.effective_taps())
+_compiled = _EngineCache()
+_clear_compile_caches = _compiled.cache_clear
 
 
 def compiled_engine(spec: ExperimentSpec, *, shard: bool = False,
                     faulted: bool = False, fault_axis: bool = False) -> Callable:
     """The spec's compiled engine (public access to the cache)."""
-    return _compiled(*_engine_key(spec, shard=shard, faulted=faulted,
-                                  fault_axis=fault_axis))
-
-
-def _clear_compile_caches() -> None:
-    _day_core.cache_clear()
-    _compiled_raw.cache_clear()
-    obs.spans.note_eviction()
-
-
-_compiled.cache_clear = _clear_compile_caches
+    return _compiled(_engine_key(spec, shard=shard, faulted=faulted,
+                                 fault_axis=fault_axis))
 
 
 # re-registering a technique name must not serve stale compiled engines
@@ -598,7 +612,7 @@ def _run_scan(spec, env, peak_state0, solver_state0, faults=None):
                                   solver_state0, spec.routed)
         peak0 = (peak_state0 if peak_state0 is not None
                  else jnp.zeros((E.num_dcs(env),)))
-    day = _compiled(*_engine_key(spec, faulted=faults is not None))
+    day = compiled_engine(spec, faulted=faults is not None)
     _dispatched(1, spec.hours)
     if faults is None:
         _, _, ms = day(env, key, peak0, state0)
@@ -707,8 +721,8 @@ def _run_batched(spec, envs, solver_state0, shard, faults=None):
                     lambda x: jnp.concatenate(
                         [x, jnp.broadcast_to(x[-1:], (pad,) + x.shape[1:])]),
                     faults),)
-    batch = _compiled(*_engine_key(spec, shard=shard, faulted=faulted,
-                                   fault_axis=stacked))
+    batch = compiled_engine(spec, shard=shard, faulted=faulted,
+                            fault_axis=stacked)
     _dispatched(n + pad, n * spec.hours)
     _, _, ms = batch(env_b, keys, peak0, state0, *trace)
     out = _fetch(ms, n if pad else None)  # (n, hours) each
@@ -742,7 +756,7 @@ def _run_month(spec, envs, peak_state0, solver_state0):
         peak0 = (peak_state0 if peak_state0 is not None
                  else jnp.zeros((E.num_dcs(env0),)))
 
-    month = _compiled(*_engine_key(spec))
+    month = compiled_engine(spec)
     _dispatched(n, n * spec.hours)
     final_peak, _, ms, peaks = month(env_days, keys, peak0, state0)
     per_day = _fetch(ms)  # (n, hours) each

@@ -9,8 +9,9 @@ checker         invariant
 ``purity``      nothing host-side (clocks, ``np.random``, ``.item()``,
                 global mutation, un-pragma'd callbacks) is reachable from
                 jitted roots
-``compile-key`` every trace-influencing ``ExperimentSpec`` field joins the
-                engine compile key (the PR 6/7/8 stale-artifact bug class)
+``compile-key`` every ``ExperimentSpec`` field is an ``EngineKey`` field,
+                ``engine`` or declared runtime-only (the PR 6/7/8
+                stale-artifact bug class)
 ``pytree``      ``EnvParams`` / ``FaultTrace`` / ``CapabilityBundle`` match
                 their declared shape schemas; construction is total
 ``taps``        every ``obs.tap("...")`` literal is a declared tap name

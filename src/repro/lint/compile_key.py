@@ -3,101 +3,39 @@ field must join the engine compile key.
 
 This is the PR 6/7/8 bug class, mechanized. Three consecutive PRs each
 added a field that changes the traced program (``taps``, faultedness,
-``workload``) and each initially forgot to join it to the key tuple in
-``repro.core.experiment`` — so a stale jitted artifact kept dispatching for
-specs that described a different program. The checker cross-references, in
-source, the four places a field can appear:
+``workload``) and each forgot one copy of the key, so a stale jitted
+artifact kept dispatching for specs that described a different program.
+The key is now one frozen record, ``EngineKey`` in
+``repro.core.experiment``, built by keyword, so no copy of it can drift.
+What is left to check is that every ``ExperimentSpec`` field is classified
+as exactly one of:
 
-1. the ``ExperimentSpec`` dataclass fields,
-2. the ``self.<field>`` reads inside ``static_key``,
-3. the parameters of ``_day_core`` (what actually shapes the traced
-   program) and ``_compiled_raw`` (the cache key arity),
-4. the tuple ``_engine_key`` builds (the key ``run`` dispatches under).
+- a field of ``EngineKey``;
+- ``engine``, which the key carries as its ``kind``;
+- annotated ``# lint: runtime-only(reason)`` on its declaration line: it
+  only selects runtime inputs. Such a field may not also be a field of
+  ``EngineKey``.
 
-and fails when they drift:
-
-- a ``_day_core`` parameter that is not read by ``static_key`` — the
-  seeded regression "delete ``workload`` from ``static_key``" trips here;
-- a spec field that is neither in ``static_key``, nor ``engine``/``taps``
-  (keyed via ``kind``/``effective_taps``), nor explicitly annotated
-  ``# lint: runtime-only(reason)`` on its declaration line — adding a new
-  field forces a decision: join the key, or declare (with a reason) that
-  it only selects runtime inputs;
-- a ``runtime-only`` field that *is* in ``static_key`` (contradiction);
-- ``_engine_key``'s unpack order or return tuple drifting out of
-  positional agreement with ``static_key`` / ``_compiled_raw`` (the key is
-  splatted positionally — ``_compiled_raw(*key)`` — so order IS meaning);
-- ``spec.effective_taps()`` missing from the key tuple (taps are
-  trace-time liveness: an artifact traced under the wrong tap set either
-  streams to nobody or never streams).
+Adding a spec field therefore forces a decision: join the key, or declare
+(with a reason) that it never changes the program.
 """
 from __future__ import annotations
 
 import ast
-from typing import Dict, List, Optional, Sequence
+from typing import Dict, List, Optional
 
 from .project import Project, Violation
 
 EXPERIMENT_PATH = "src/repro/core/experiment.py"
 
-#: fields keyed through a named transformation rather than static_key:
-#: ``engine`` becomes the key's leading ``kind``; ``taps`` rides
-#: ``spec.effective_taps()`` (tap liveness is trace-time state).
-INDIRECTLY_KEYED = {"engine", "taps"}
 
-
-def _find(tree: ast.Module, name: str,
-          cls: Optional[str] = None) -> Optional[ast.AST]:
+def _fields(tree: ast.Module, cls: str) -> Optional[Dict[str, int]]:
+    """Dataclass field name -> declaration line (None: no such class)."""
     for node in tree.body:
-        if cls is None and isinstance(node, (ast.FunctionDef, ast.ClassDef)) \
-                and node.name == name:
-            return node
-        if cls is not None and isinstance(node, ast.ClassDef) \
-                and node.name == cls:
-            for sub in node.body:
-                if isinstance(sub, ast.FunctionDef) and sub.name == name:
-                    return sub
-    return None
-
-
-def _self_reads(fn: ast.AST) -> List[str]:
-    """``self.X`` attribute reads, in source order."""
-    out = []
-    for node in ast.walk(fn):
-        if isinstance(node, ast.Attribute) and \
-                isinstance(node.value, ast.Name) and node.value.id == "self":
-            out.append(node.attr)
-    return out
-
-
-def _params(fn: ast.FunctionDef) -> List[str]:
-    a = fn.args
-    return [p.arg for p in a.posonlyargs + a.args]
-
-
-def _spec_fields(cls: ast.ClassDef) -> Dict[str, int]:
-    """Dataclass field name -> declaration line."""
-    return {n.target.id: n.lineno for n in cls.body
-            if isinstance(n, ast.AnnAssign) and isinstance(n.target, ast.Name)}
-
-
-def _return_tuple(fn: ast.FunctionDef) -> Optional[Sequence[ast.expr]]:
-    """The elements of the function's (last) ``return (a, b, ...)``."""
-    rets = [n for n in ast.walk(fn) if isinstance(n, ast.Return)]
-    for r in reversed(rets):
-        if isinstance(r.value, ast.Tuple):
-            return r.value.elts
-    return None
-
-
-def _key_element_name(e: ast.expr) -> Optional[str]:
-    """Map one ``_engine_key`` return element to the spec concept it keys:
-    plain names pass through; ``spec.effective_taps()`` counts as ``taps``."""
-    if isinstance(e, ast.Name):
-        return e.id
-    if isinstance(e, ast.Call) and isinstance(e.func, ast.Attribute) \
-            and e.func.attr == "effective_taps":
-        return "taps"
+        if isinstance(node, ast.ClassDef) and node.name == cls:
+            return {n.target.id: n.lineno for n in node.body
+                    if isinstance(n, ast.AnnAssign)
+                    and isinstance(n.target, ast.Name)}
     return None
 
 
@@ -107,125 +45,30 @@ def check(project: Project) -> List[Violation]:
         return [Violation(EXPERIMENT_PATH, 1, "compile-key",
                           "cannot parse repro/core/experiment.py — the "
                           "compile-key contract is unverifiable")]
-    out: List[Violation] = []
     rel = sf.relpath
-
-    spec_cls = _find(sf.tree, "ExperimentSpec")
-    static_key = _find(sf.tree, "static_key", cls="ExperimentSpec")
-    effective_taps = _find(sf.tree, "effective_taps", cls="ExperimentSpec")
-    day_core = _find(sf.tree, "_day_core")
-    compiled_raw = _find(sf.tree, "_compiled_raw")
-    engine_key = _find(sf.tree, "_engine_key")
-    for name, node in (("ExperimentSpec", spec_cls),
-                       ("ExperimentSpec.static_key", static_key),
-                       ("_day_core", day_core),
-                       ("_compiled_raw", compiled_raw),
-                       ("_engine_key", engine_key)):
-        if node is None:
-            out.append(Violation(
-                rel, 1, "compile-key",
-                f"`{name}` not found — the compile-key contract this "
-                "checker enforces has moved; update repro.lint.compile_key"))
-    if any(n is None for n in (spec_cls, static_key, day_core,
-                               compiled_raw, engine_key)):
-        return out
-
-    fields = _spec_fields(spec_cls)
-    key_fields = [f for f in _self_reads(static_key)]
-
-    # 1. every spec field is keyed, indirectly keyed, or declared runtime-only
-    for field, line in fields.items():
-        if field in key_fields:
-            if project.pragma_at(rel, line, "runtime-only"):
-                project.use_pragma(rel, line)
-                out.append(Violation(
-                    rel, line, "compile-key",
-                    f"spec field `{field}` is declared runtime-only but IS "
-                    "read by static_key — one of the two is wrong"))
-            continue
-        if field in INDIRECTLY_KEYED:
-            continue
-        pragma = project.pragma_at(rel, line, "runtime-only")
-        if pragma is not None:
+    spec = _fields(sf.tree, "ExperimentSpec")
+    key = _fields(sf.tree, "EngineKey")
+    if spec is None or key is None:
+        return [Violation(rel, 1, "compile-key",
+                          "`ExperimentSpec` or `EngineKey` not found — the "
+                          "compile-key contract this checker enforces has "
+                          "moved; update repro.lint.compile_key")]
+    out: List[Violation] = []
+    for field, line in spec.items():
+        runtime_only = project.pragma_at(rel, line, "runtime-only") is not None
+        if runtime_only:
             project.use_pragma(rel, line)
-            continue
-        out.append(Violation(
-            rel, line, "compile-key",
-            f"ExperimentSpec field `{field}` is in no compile key: join it "
-            "to static_key() if it can change the traced program, or "
-            "annotate the field `# lint: runtime-only(reason)` if it only "
-            "selects runtime inputs (the PR 6/7/8 stale-artifact bug class)"))
-
-    # 2. `taps`/`engine` indirection actually holds
-    if effective_taps is None or "taps" not in _self_reads(effective_taps):
-        out.append(Violation(
-            rel, spec_cls.lineno, "compile-key",
-            "ExperimentSpec.effective_taps() no longer reads self.taps — "
-            "the taps field would fall out of the compile key"))
-    eng_reads = [n.attr for n in ast.walk(engine_key)
-                 if isinstance(n, ast.Attribute)
-                 and isinstance(n.value, ast.Name) and n.value.id == "spec"]
-    if "engine" not in eng_reads:
-        out.append(Violation(
-            rel, engine_key.lineno, "compile-key",
-            "_engine_key no longer reads spec.engine — the engine kind "
-            "would fall out of the compile key"))
-
-    # 3. every _day_core parameter that shapes the traced program is keyed
-    for p in _params(day_core):
-        if p in ("faulted", "taps"):   # joined downstream of static_key
-            continue
-        if p not in key_fields:
+        if field in key and runtime_only:
             out.append(Violation(
-                rel, day_core.lineno, "compile-key",
-                f"_day_core parameter `{p}` changes the traced program but "
-                "is not read by ExperimentSpec.static_key() — engines would "
-                "reuse a stale compiled artifact across different "
-                f"`{p}` values"))
-
-    # 4. _engine_key's static_key unpack preserves static_key's field order
-    unpack: Optional[List[str]] = None
-    for node in ast.walk(engine_key):
-        if isinstance(node, ast.Assign) and len(node.targets) == 1 \
-                and isinstance(node.targets[0], ast.Tuple) \
-                and isinstance(node.value, ast.Call) \
-                and isinstance(node.value.func, ast.Attribute) \
-                and node.value.func.attr == "static_key":
-            unpack = [e.id for e in node.targets[0].elts
-                      if isinstance(e, ast.Name)]
-    if unpack is None:
-        out.append(Violation(
-            rel, engine_key.lineno, "compile-key",
-            "_engine_key no longer unpacks spec.static_key() — key "
-            "construction has drifted from the declared static fields"))
-    elif unpack != key_fields:
-        out.append(Violation(
-            rel, engine_key.lineno, "compile-key",
-            f"_engine_key unpacks static_key() as {unpack} but static_key "
-            f"returns {key_fields} — the key tuple is splatted positionally "
-            "(_compiled_raw(*key)), so order drift silently rebinds fields"))
-
-    # 5. the key tuple lines up 1:1 with _compiled_raw's parameters
-    raw_params = _params(compiled_raw)
-    ret = _return_tuple(engine_key)
-    if ret is None:
-        out.append(Violation(
-            rel, engine_key.lineno, "compile-key",
-            "_engine_key does not return a tuple literal — the key's "
-            "positional contract with _compiled_raw is unverifiable"))
-    else:
-        key_names = [_key_element_name(e) for e in ret]
-        if "taps" not in key_names:
+                rel, line, "compile-key",
+                f"spec field `{field}` is declared runtime-only but IS an "
+                "EngineKey field — one of the two is wrong"))
+        elif field not in key and field != "engine" and not runtime_only:
             out.append(Violation(
-                rel, engine_key.lineno, "compile-key",
-                "spec.effective_taps() is missing from _engine_key's tuple "
-                "— tapped and untapped programs would share one artifact"))
-        if len(key_names) != len(raw_params) or any(
-                k is not None and k != p
-                for k, p in zip(key_names, raw_params)):
-            out.append(Violation(
-                rel, engine_key.lineno, "compile-key",
-                f"_engine_key tuple {key_names} does not line up with "
-                f"_compiled_raw{tuple(raw_params)} — the key is applied "
-                "positionally, so a mismatch rebinds every later field"))
+                rel, line, "compile-key",
+                f"ExperimentSpec field `{field}` is in no compile key: add "
+                "it to EngineKey (and _engine_key) if it can change the "
+                "traced program, or annotate the field "
+                "`# lint: runtime-only(reason)` if it only selects runtime "
+                "inputs (the PR 6/7/8 stale-artifact bug class)"))
     return out

@@ -9,7 +9,7 @@ fooled by import-time state). Everything they share lives here:
   once, maps files to dotted module names so imports resolve across the
   package, and supports :meth:`Project.overlay` — swap one file's source
   for a modified string — which is how the tests seed regressions (delete a
-  field from ``static_key``, typo a tap name) without touching the tree.
+  field from ``EngineKey``, typo a tap name) without touching the tree.
 - :class:`Pragma` — the in-source suppression grammar
   ``# lint: <directive>(<reason>)``. Directives: ``host-ok`` (this line's
   host-side call from traced code is deliberate — the ``jax.debug.callback``

@@ -58,9 +58,8 @@ from . import records, report as report_mod, spans, tap as tap_mod
 from .records import (load_records, make_record, run_info, spec_fields,
                       spec_key, write_record)
 from .report import report, sparkline
-from .spans import (Request, Span, cache_stats, count, engine_key_str,
-                    engine_stat, profile, request, requests, reset_stats,
-                    span)
+from .spans import (Request, Span, cache_stats, count, engine_stat, profile,
+                    request, requests, reset_stats, span)
 from .spans import spans as all_spans
 from .tap import (KNOWN_TAPS, TapBuffer, TapEvent, active_taps, capture,
                   clear_events, disable_taps, enable_taps, enabled, events,
@@ -71,8 +70,7 @@ __all__ = [
     "enable_taps", "disable_taps", "enabled", "active_taps", "tracing",
     "KNOWN_TAPS", "TapBuffer", "TapEvent",
     "span", "all_spans", "Span", "request", "requests", "Request", "count",
-    "cache_stats", "engine_stat", "engine_key_str",
-    "reset_stats", "profile",
+    "cache_stats", "engine_stat", "reset_stats", "profile",
     "make_record", "write_record", "load_records", "run_info",
     "spec_fields", "spec_key",
     "report", "sparkline",

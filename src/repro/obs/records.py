@@ -10,8 +10,9 @@ emit through here, so "our strategy outperforms" becomes a committed,
 regenerable artifact (``repro.obs.report`` renders a scoreboard from these
 files) instead of an ad-hoc example-script printout.
 
-This module is provenance only — it never imports ``repro.core``; specs
-arrive duck-typed (any frozen dataclass with the ExperimentSpec fields).
+This module is provenance only — specs arrive duck-typed (any frozen
+dataclass with the ExperimentSpec fields); it reads ``repro.core`` only for
+the compile key's declaration (``spec_key``).
 """
 from __future__ import annotations
 
@@ -77,9 +78,15 @@ def spec_fields(spec) -> Dict[str, Any]:
 
 
 def spec_key(spec) -> str:
-    """Stable short hash of the spec's compile-relevant (static) fields —
-    the join key between records, cache stats and compiled artifacts."""
-    return hashlib.sha1(repr(spec.static_key()).encode()).hexdigest()[:12]
+    """Stable short hash of the spec's compile-relevant fields — the spec
+    fields ``EngineKey`` declares, in its order, but ``taps`` (resolved at
+    call time) — the join key between records, cache stats and compiled
+    artifacts."""
+    from ..core.experiment import EngineKey
+    own = {f.name for f in dataclasses.fields(spec)} - {"taps"}
+    keyed = tuple(getattr(spec, f.name) for f in dataclasses.fields(EngineKey)
+                  if f.name in own)
+    return hashlib.sha1(repr(keyed).encode()).hexdigest()[:12]
 
 
 def curves_from_result(result: Dict[str, Any],

@@ -23,7 +23,8 @@ tell them apart. Four pieces:
   returns the last ``n`` finished requests with their counters and the
   self time of each child span name.
 - engine-cache accounting — ``repro.core.experiment._compiled`` reports
-  every lookup (``engine_lookup``), wraps every artifact's dispatch
+  every lookup (``engine_lookup``) under an opaque key it labels with
+  ``str(key)``, wraps every artifact's dispatch
   (``instrument_dispatch``: an ``engine.dispatch`` span per call, the
   first-dispatch time ≈ trace+XLA-compile+run, and the trace-time tap
   pinning), and reports evictions (``note_eviction``, fired by
@@ -49,7 +50,7 @@ import itertools
 import threading
 import time
 from contextlib import contextmanager
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, Hashable, List, Optional
 
 from jax.profiler import TraceAnnotation
 
@@ -218,31 +219,15 @@ _engine: Dict[str, EngineStat] = {}      # resettable accounting, by key string
 _evictions: int = 0
 
 
-def engine_key_str(key: tuple) -> str:
-    """Compact, human-scannable form of an engine compile key:
-    ``kind:technique:objective:h<hours>:cfg=<...>:routed=<...>:
-    wl=<workload>:faults=<policy|off[/point]>:guard=<on|off>:taps=<...>``."""
-    (kind, technique, objective, hours, cfg, routed, failover, guard,
-     workload, faulted, fault_axis, taps) = key
-    cfg_s = "default" if cfg is None else type(cfg).__name__
-    taps_s = ",".join(sorted(taps)) if taps else "off"
-    faults_s = failover if faulted else "off"
-    if faulted and fault_axis:
-        faults_s += "/point"  # one trace per env row
-    return (f"{kind}:{technique}:{objective}:h{hours}:cfg={cfg_s}:"
-            f"routed={bool(routed)}:wl={workload}:faults={faults_s}:"
-            f"guard={'on' if guard else 'off'}:taps={taps_s}")
-
-
-def _stat(key: tuple) -> EngineStat:
-    ks = engine_key_str(key)
+def _stat(key: Hashable) -> EngineStat:
+    ks = str(key)
     st = _engine.get(ks)
     if st is None:
         st = _engine[ks] = EngineStat()
     return st
 
 
-def engine_lookup(key: tuple) -> bool:
+def engine_lookup(key: Hashable) -> bool:
     """Count one compile-cache lookup; returns True on a hit."""
     hit = key in _known
     st = _stat(key)
@@ -254,7 +239,7 @@ def engine_lookup(key: tuple) -> bool:
     return hit
 
 
-def note_build(key: tuple, seconds: float) -> None:
+def note_build(key: Hashable, seconds: float) -> None:
     _stat(key).build_s += seconds
 
 
@@ -269,12 +254,12 @@ def note_eviction() -> None:
         st.evicted = True
 
 
-def instrument_dispatch(key: tuple, fn: Callable) -> Callable:
+def instrument_dispatch(key: Hashable, taps: frozenset,
+                        fn: Callable) -> Callable:
     """Wrap a compiled engine so every call is a timed span, the first call
     is recorded as the compile span, and tracing happens under exactly the
-    key's tap set (see ``tap.tracing``)."""
+    tap set ``taps`` (see ``tap.tracing``)."""
     import jax
-    taps = key[-1]
 
     def dispatch(*args, **kwargs):
         st = _stat(key)
@@ -306,8 +291,8 @@ def cache_stats() -> Dict[str, Any]:
     }
 
 
-def engine_stat(key: tuple) -> Optional[Dict[str, Any]]:
-    st = _engine.get(engine_key_str(key))
+def engine_stat(key: Hashable) -> Optional[Dict[str, Any]]:
+    st = _engine.get(str(key))
     return None if st is None else st.as_dict()
 
 

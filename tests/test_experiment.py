@@ -111,7 +111,7 @@ def test_compile_cache_shared_across_entry_points():
 
 
 def test_non_static_fields_never_key_a_new_compile():
-    """Every field outside ``static_key()`` (seed, seeds, days, pretrain) is
+    """Every spec field outside ``EngineKey`` (seed, seeds, days, pretrain) is
     a runtime input: varying them must hit the SAME compiled artifact, and
     the obs accounting must agree (one miss total, the rest hits)."""
     from repro import obs

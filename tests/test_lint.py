@@ -48,9 +48,10 @@ def test_repo_lints_clean():
 # ---------------------------------------------------------------------------
 
 def test_dropping_workload_from_static_key_trips_compile_key():
+    # the key is the EngineKey record: drop its `workload` field
     p = _seed("src/repro/core/experiment.py",
-              "self.routed, self.failover, self.guard, self.workload)",
-              "self.routed, self.failover, self.guard)")
+              "    guard: bool\n    workload: str\n    faulted: bool\n",
+              "    guard: bool\n    faulted: bool\n")
     assert _hits(p, "compile-key", "workload")
 
 

@@ -57,9 +57,35 @@ def test_tapped_and_untapped_artifacts_coexist_under_distinct_keys():
     key_on = X._engine_key(SPEC.replace(taps=("engine/hour",)))
     assert key_off != key_on
     stats = obs.cache_stats()
-    assert obs.engine_key_str(key_off) in stats["engines"]
-    assert obs.engine_key_str(key_on) in stats["engines"]
-    assert stats["engines"][obs.engine_key_str(key_on)]["dispatches"] >= 1
+    assert str(key_off) in stats["engines"]
+    assert str(key_on) in stats["engines"]
+    assert stats["engines"][str(key_on)]["dispatches"] >= 1
+
+
+# pinned: run records (runs/records.jsonl) and cache-stats readers join on
+# these labels, so a change to the key's declaration must not move them
+_POINT = SPEC.replace(engine="batched", routed=True, failover="spill_nearest",
+                      taps=("engine/hour",))
+_GTDRL = ExperimentSpec(technique="gt-drl", objective="cost_sla", routed=True,
+                        guard=True, workload="llm-mix", failover="drop",
+                        seed=5, days=3, taps=("engine/hour",))
+KEY_LABELS = [
+    (lambda: str(X._engine_key(SPEC)),
+     "day:fd:carbon:h4:cfg=FDConfig:routed=False:wl=aibench:faults=off:"
+     "guard=off:taps=off"),
+    (lambda: str(X._engine_key(_POINT, faulted=True, fault_axis=True)),
+     "batched:fd:carbon:h4:cfg=FDConfig:routed=True:wl=aibench:"
+     "faults=spill_nearest/point:guard=off:taps=engine/hour"),
+    (lambda: obs.spec_key(SPEC), "53fd3bce51cd"),
+    (lambda: obs.spec_key(_GTDRL), "c469e3130ffc"),
+]
+
+
+@pytest.mark.parametrize("label,expected", KEY_LABELS,
+                         ids=["str-scan", "str-faulted-point", "spec_key-fd",
+                              "spec_key-gtdrl"])
+def test_key_labels_keep_their_values(label, expected):
+    assert label() == expected
 
 
 def test_tap_series_equals_engine_per_epoch_exactly():
@@ -316,11 +342,12 @@ def test_named_scopes_reach_the_compiled_hlo():
     from repro import faults as FL
     key, state0 = X._day_inputs(ENV, "fd", "carbon", 0, True, FD_CFG)
     peak0 = jnp.zeros((E.num_dcs(ENV),))
-    plain = X._day_core("fd", "carbon", 2, FD_CFG)
+    spec = SPEC.replace(hours=2)
+    plain = X._day_core(X._engine_key(spec))
     txt = jax.jit(plain).lower(ENV, key, peak0, state0).compile().as_text()
     assert "solver/" in txt and "simulate/" in txt
     assert "failover/" not in txt
-    faulted = X._day_core("fd", "carbon", 2, FD_CFG, faulted=True)
+    faulted = X._day_core(X._engine_key(spec, faulted=True))
     trace = FL.random_trace(ENV, seed=0)
     txt = jax.jit(faulted).lower(ENV, key, peak0, state0,
                                  trace).compile().as_text()
